@@ -90,17 +90,6 @@ def run(full: bool = False, smoke: bool = False):
     common.csv_row("kernel/fused_enhance", us,
                    f"match_ref={ok};passes_fused=1;passes_unfused=4")
 
-    xx = jnp.asarray(np.random.default_rng(2)
-                     .standard_normal((8, 64, 64, 4)), jnp.float32)
-    w = jnp.asarray(np.random.default_rng(3)
-                    .standard_normal((3, 3, 4, 8)) * 0.1, jnp.float32)
-    b = jnp.zeros((8,), jnp.float32)
-    us = _time(jax.jit(lambda a, ww, bb: ref.conv2d3x3_ref(a, ww, bb, stride=2)), xx, w, b)
-    y = ops.conv3x3(xx, w, b, stride=2)
-    yr = ref.conv2d3x3_ref(xx, w, b, stride=2)
-    ok = bool(jnp.allclose(y, yr, atol=1e-5))
-    common.csv_row("kernel/conv2d3x3_s2", us, f"match_ref={ok}")
-
     _dnn_forward_row(full, smoke)
 
 

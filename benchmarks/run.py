@@ -64,6 +64,8 @@ def main() -> None:
                     help="write the run's rows as JSON here (--smoke "
                          f"defaults to <repo-root>/{LEDGER})")
     args = ap.parse_args()
+    from repro import compile_cache
+    compile_cache.setup()
 
     failures = 0
     ran = 0

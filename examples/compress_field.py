@@ -16,6 +16,7 @@ import tempfile
 import numpy as np
 
 import repro
+from repro import compile_cache
 from repro.compressors import registry
 from repro.core import metrics
 from repro.data import fields as F
@@ -104,6 +105,7 @@ def main():
                          "the checksum path and report per-entry status")
     ap.add_argument("--out", default=None)
     args = ap.parse_args()
+    compile_cache.setup()
 
     if args.list_compressors:
         list_compressors()

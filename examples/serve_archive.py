@@ -19,6 +19,7 @@ import tempfile
 import time
 
 import repro
+from repro import compile_cache
 from repro.serve import ArchiveServer, transcode
 from repro.streaming.pipeline import ResidencyLedger
 
@@ -47,6 +48,7 @@ def main():
                     help="serve this existing container instead of "
                          "compressing a synthetic snapshot")
     args = ap.parse_args()
+    compile_cache.setup()
 
     tmp = tempfile.mkdtemp(prefix="repro-serve-")
     path = args.serve or os.path.join(tmp, "snapshot.nlzs")

@@ -33,6 +33,7 @@ several compressors may share an archive format (``szlike`` and
 from __future__ import annotations
 
 import dataclasses
+import functools
 import threading
 from typing import Callable
 
@@ -229,13 +230,50 @@ class DecodeStats:
                 f"archives={self.archives}, max_width={self.max_width})")
 
 
+# Peak device bytes one field of a stacked (de)compress holds, per point.
+# The interp predictor runs its phases eagerly in float64 with the
+# reconstruction, the targets, shifted neighbour copies and the functional
+# ``.at[].set`` update all live: on a v5e chip (16 GiB HBM) two stacked
+# 513³ fields ran out of device memory with more than 7 f64 words per point
+# in use, so 12 words is budgeted.
+STACK_BYTES_PER_POINT = 96
+
+
+@functools.lru_cache(maxsize=1)
+def _device_bytes_limit() -> int | None:
+    """The first device's memory limit, asked once per process (None where
+    the backend reports none, as the CPU does)."""
+    import jax
+    stats = jax.devices()[0].memory_stats()
+    limit = (stats or {}).get("bytes_limit")
+    return int(limit) if limit else None
+
+
+def stack_limit(points: int) -> int | None:
+    """How many same-shape fields of ``points`` points one stacked call may
+    carry on the default device, or ``None`` where the backend reports no
+    memory limit: at least 1, so a field too big to stack still runs
+    alone."""
+    limit = _device_bytes_limit()
+    if limit is None:
+        return None
+    return max(1, limit // (int(points) * STACK_BYTES_PER_POINT))
+
+
+def stack_chunks(members: list, points: int) -> list[list]:
+    """``members`` split into runs that fit :func:`stack_limit`."""
+    cap = stack_limit(points) or len(members) or 1
+    return [members[i:i + cap] for i in range(0, len(members), cap)]
+
+
 def decompress_many(arcs, *, batch: bool = True,
                     stats: DecodeStats | None = None) -> dict:
     """Decode a set of conventional archives, batching where possible.
 
     ``arcs`` maps name -> archive dict.  Archives whose entry declares
     ``decompress_batched`` and that agree on the entry's ``decode_key``
-    run as one stacked eager dispatch; everything else decodes per-archive.
+    run as one stacked eager dispatch (in runs that fit the device's
+    memory, :func:`stack_chunks`); everything else decodes per-archive.
     Outputs are bit-identical to per-archive :func:`decompress` either way
     (the decode-side mirror of the conv stage's encode contract), so every
     caller — batched-engine decode, streaming ``iter_decompress``, the
@@ -252,19 +290,22 @@ def decompress_many(arcs, *, batch: bool = True,
         else:
             k = (entry.name, ("__single__", name))
         groups.setdefault(k, []).append((name, arc, entry))
-    for members in groups.values():
-        entry = members[0][2]
-        if len(members) > 1:    # only decode_key-matched archives group
-            recs = entry.decompress_batched([arc for _, arc, _ in members])
-            for (name, _, _), rec in zip(members, recs):
-                out[name] = rec
-            if stats is not None:
-                stats.note(len(members))
-        else:
-            for name, arc, e in members:
-                out[name] = e.decompress(arc)
+    for group in groups.values():
+        entry = group[0][2]
+        points = int(np.prod(group[0][1].get("shape", ())))
+        for members in stack_chunks(group, points):
+            if len(members) > 1:    # only decode_key-matched archives group
+                recs = entry.decompress_batched(
+                    [arc for _, arc, _ in members])
+                for (name, _, _), rec in zip(members, recs):
+                    out[name] = rec
                 if stats is not None:
-                    stats.note(1)
+                    stats.note(len(members))
+            else:
+                for name, arc, e in members:
+                    out[name] = e.decompress(arc)
+                    if stats is not None:
+                        stats.note(1)
     return {name: out[name] for name in arcs}
 
 

@@ -21,10 +21,8 @@ resident together), so this engine restructures the hot path around the
     the serial engine.
   * **``field_batching="vmap"``** — per-field params are stacked on a
     leading ``F`` axis (:func:`repro.core.skipping_dnn.stack_params`) and
-    each epoch runs as one ``jax.vmap``-over-fields ``lax.scan``; the
-    stacked axis can be sharded across devices
-    (:func:`repro.distributed.sharding.field_sharding`,
-    ``field_shard=True``).  The skipping-DNN forward is built from
+    each epoch runs as one ``jax.vmap``-over-fields ``lax.scan``.  The
+    skipping-DNN forward is built from
     shift-and-accumulate ``lax.dot_general`` contractions that lower
     identically under ``vmap`` (see :mod:`repro.core.skipping_dnn`), so
     equal-slice-count groups are bit-identical to serial at most training
@@ -66,7 +64,6 @@ import numpy as np
 
 from .. import faults as faults_lib
 from ..compressors import registry
-from ..distributed import sharding as shardlib
 from ..obs import telemetry as obs_lib
 from ..optim import adamw_init, adamw_update, cosine_schedule
 from . import bounds as bounds_lib
@@ -261,13 +258,14 @@ class _GroupState:
     total_steps: list  # per-field cosine horizon
     losses: object = None   # device [epochs, F] once training is dispatched
     resids: tuple = ()      # per-field lazy [N, H, W] residual predictions
+    devices: tuple = ()     # ids of the devices holding the training arrays
 
 
 def _prepare_group(group: FieldGroup, fields, recs, ebs, config, tcfg,
                    device=None) -> _GroupState:
     """Host-side stage: datasets + async device upload + param init.
 
-    ``device`` pins the whole group (unroll-mode field sharding: groups are
+    ``device`` pins the whole group (field sharding: groups are
     round-robined over devices, and jit runs each group's program where its
     operands live — identical programs, so results stay bit-identical)."""
     config = group_config(config, group)
@@ -366,6 +364,10 @@ def vmap_bit_parity(net_cfg, slice_hw: tuple, batch: int, tcfg) -> bool:
     return ok
 
 
+def _device_ids(x) -> tuple:
+    return tuple(sorted(d.id for d in x.devices()))
+
+
 def _dispatch_group(state: _GroupState, config, tcfg) -> None:
     """Enqueue the group's full training AND inference without blocking."""
     net_cfg = state.net_cfg
@@ -378,8 +380,9 @@ def _dispatch_group(state: _GroupState, config, tcfg) -> None:
                                min(tcfg.batch, n_max), tcfg):
             strategy = "unroll"
     if strategy == "vmap":
-        _dispatch_vmapped(state, config, tcfg, key)
+        _dispatch_vmapped(state, tcfg, key)
     elif strategy == "unroll":
+        state.devices = _device_ids(state.inputs[0])
         if tcfg.epochs <= 0:
             state.losses = jnp.zeros((0, len(state.group.names)), jnp.float32)
         else:
@@ -403,7 +406,7 @@ def _dispatch_group(state: _GroupState, config, tcfg) -> None:
                                   spec=pspec, lowering=tcfg.lowering)
 
 
-def _dispatch_vmapped(state: _GroupState, config, tcfg, key) -> None:
+def _dispatch_vmapped(state: _GroupState, tcfg, key) -> None:
     """vmap strategy: stack fields, pad ragged slice counts, train stacked."""
     net_cfg = state.net_cfg
     n_max = max(int(x.shape[0]) for x in state.inputs)
@@ -420,13 +423,7 @@ def _dispatch_vmapped(state: _GroupState, config, tcfg, key) -> None:
     params_st = skipping_dnn.stack_params(list(state.params))
     opt_st = jax.tree.map(lambda *a: jnp.stack(a), *state.opt)
     n_valid = jnp.asarray([x.shape[0] for x in state.inputs], jnp.int32)
-    if config.field_shard:
-        mesh = shardlib.field_mesh()
-        if mesh is not None:
-            xs = shardlib.shard_fields(xs, mesh)
-            ys = shardlib.shard_fields(ys, mesh)
-            params_st = shardlib.shard_fields(params_st, mesh)
-            opt_st = shardlib.shard_fields(opt_st, mesh)
+    state.devices = _device_ids(xs)
     losses = []
     for e in range(tcfg.epochs):
         ekey = jax.random.fold_in(key, e)
@@ -570,9 +567,12 @@ def compress(fields: Mapping[str, np.ndarray], rel_eb: float | None = None, *,
         if config.cross_field or not config.prefetch:
             conv_compress(list(fields))
 
-        # Unroll-mode field sharding: spread groups across training devices —
-        # all but the conventional-compressor device, so conv work never
-        # shares a queue with enhancer training.
+        # Field sharding: spread whole groups across training devices — all
+        # but the conventional-compressor device, so conv work never shares
+        # a queue with enhancer training.  A group's program runs unsplit
+        # on its device: splitting a stacked group's field axis over a mesh
+        # changes each device's batched contractions, and with them the
+        # archive bytes.
         train_devs = jax.devices()
         if conv_dev is not None and len(train_devs) > 1:
             train_devs = train_devs[:-1]
@@ -588,17 +588,13 @@ def compress(fields: Mapping[str, np.ndarray], rel_eb: float | None = None, *,
         states: list[_GroupState] = []
         for gi, group in enumerate(groups):
             conv_compress(group.names)
-            counts = [sliced_shape(np.asarray(fields[n]).shape,
-                                   config.slice_axis)[0]
-                      for n in group.names]
-            strategy = resolve_batching(config.field_batching, counts)
             dev = train_devs[gi % len(train_devs)] \
-                if (config.field_shard and len(train_devs) > 1
-                    and strategy == "unroll") else None
-            with tel.span("train", group=",".join(group.names)):
+                if config.field_shard and len(train_devs) > 1 else None
+            with tel.span("train", group=",".join(group.names)) as sp:
                 state = _prepare_group(group, fields, recs, ebs, config,
                                        tcfg, device=dev)
                 _dispatch_group(state, config, tcfg)   # async: no host sync
+                sp.set(devices=list(state.devices))
             states.append(state)
             if len(states) >= depth:
                 _finalize_group(states.pop(0), fields, recs, ebs, conv_arcs,

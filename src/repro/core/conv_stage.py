@@ -142,8 +142,9 @@ class ConvStage:
         """Compress ``fields``; returns ``{name: (archive, reconstruction)}``.
 
         Same-``(shape, dtype)`` groups go through the fused batched entry
-        when the registry capability allows it; everything else runs
-        per-field.  Output payloads are byte-identical either way.
+        when the registry capability allows it, in runs that fit the
+        device's memory (:func:`repro.compressors.registry.stack_chunks`);
+        everything else runs per-field.  Output payloads are byte-identical either way.
         ``batch`` overrides the stage default for this call (the streaming
         scheduler turns it off when the fused path's working set would not
         fit its residency budget).
@@ -162,19 +163,25 @@ class ConvStage:
                 tel.gauge("conv.group_size").set(len(group))
                 dtype = metas[group[0]][1]
                 rel, ab = self.bound_for(group[0])  # one spec/group, by plan
-                if (batch and len(group) > 1
-                        and self.entry.batch_supports(dtype)):
-                    results = self.entry.compress_batched(
-                        [arrs[n] for n in group], rel, abs_eb=ab,
-                        **self._lower_kw_batched)
-                    self.stats.calls += 1
-                    self.stats.batched_fields += len(group)
-                    self.stats.lowered_calls += bool(self._lower_kw_batched)
-                    tel.counter("conv.dispatches").add()
-                    tel.counter("conv.batched_fields").add(len(group))
-                    out.update(zip(group, results))
-                else:
-                    for n in group:
+                stackable = (batch and len(group) > 1
+                             and self.entry.batch_supports(dtype))
+                chunks = (registry.stack_chunks(
+                    group, int(np.prod(metas[group[0]][0])))
+                    if stackable else [[n] for n in group])
+                for chunk in chunks:
+                    if len(chunk) > 1:
+                        results = self.entry.compress_batched(
+                            [arrs[n] for n in chunk], rel, abs_eb=ab,
+                            **self._lower_kw_batched)
+                        self.stats.calls += 1
+                        self.stats.batched_fields += len(chunk)
+                        self.stats.lowered_calls += bool(
+                            self._lower_kw_batched)
+                        tel.counter("conv.dispatches").add()
+                        tel.counter("conv.batched_fields").add(len(chunk))
+                        out.update(zip(chunk, results))
+                        continue
+                    for n in chunk:
                         out[n] = self.entry.compress(arrs[n], rel, abs_eb=ab,
                                                      **self._lower_kw)
                         self.stats.calls += 1

@@ -103,8 +103,7 @@ def param_count(params) -> int:
 
 def stack_params(params_list):
     """Stack F same-structure enhancer trees into one tree with a leading
-    field axis — the layout the batched engine trains under ``jax.vmap`` and
-    shards across devices (``repro.distributed.sharding.field_sharding``)."""
+    field axis — the layout the batched engine trains under ``jax.vmap``."""
     return jax.tree.map(lambda *xs: jnp.stack(xs), *params_list)
 
 
@@ -265,30 +264,6 @@ def forward_reference(params, x, *, regulated: bool = True,
                          conv=_conv_xla, deconv=_deconv_xla)
 
 
-def _forward_pallas(params, x, *, regulated: bool = True, skip: bool = True):
-    """Conv layers through the ``conv2d3x3`` Pallas kernel (TPU target);
-    transpose convs stay on the sub-pixel formulation.  Only engaged when
-    the parity probe proves it byte-identical to :func:`_forward_fast` on
-    this backend."""
-    from ..kernels import ops as kernel_ops
-
-    def conv(xx, p, stride=1):
-        return kernel_ops.conv3x3(xx, p["w"], p["b"], stride=stride,
-                                  relu=False)
-
-    return _forward_core(params, x, regulated=regulated, skip=skip,
-                         conv=conv, deconv=_deconv)
-
-
-def _pallas_probe() -> bool:
-    cfg = SkippingDNNConfig(c_in=1)
-    params = init_params(jax.random.PRNGKey(7), cfg)
-    x = jax.random.normal(jax.random.PRNGKey(8), (2, 17, 13, 1), jnp.float32)
-    want = np.asarray(_forward_fast(params, x, regulated=True, skip=True))
-    got = np.asarray(_forward_pallas(params, x, regulated=True, skip=True))
-    return want.tobytes() == got.tobytes()
-
-
 def forward(params, x, *, regulated: bool = True, skip: bool = True,
             lowering: str = "auto"):
     """Skipping-DNN forward under the requested lowering.
@@ -296,9 +271,10 @@ def forward(params, x, *, regulated: bool = True, skip: bool = True,
     ``eager`` and ``jit`` are the *same* compiled bit-stable fast
     formulation (it is jit-safe by construction — HIGHEST-precision GEMMs
     in a fixed accumulation order leave XLA nothing to contract), so the
-    eager/jit byte-identity half of the contract holds structurally;
-    ``pallas`` routes the convs through the hand-written kernel where the
-    parity probe passes (TPU), falling back here otherwise.  Traceable:
+    eager/jit byte-identity half of the contract holds structurally.
+    There is no Pallas variant: this forward runs inside the training
+    step's ``value_and_grad``, so every lowering must be differentiable,
+    and ``pallas``/``auto`` resolve to this same formulation.  Traceable:
     resolution happens at trace time, so callers may close over a fixed
     ``lowering`` inside their own jit/scan.
     """
@@ -310,8 +286,6 @@ def forward(params, x, *, regulated: bool = True, skip: bool = True,
 
 dispatch.register("dnn_forward", "eager", _forward_fast)
 dispatch.register("dnn_forward", "jit", _forward_fast)
-dispatch.register("dnn_forward", "pallas", _forward_pallas,
-                  probe=_pallas_probe, backends=("tpu",))
 
 
 def apply(params, x, cfg: SkippingDNNConfig):

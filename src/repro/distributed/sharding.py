@@ -173,42 +173,6 @@ def to_named(tree_specs, mesh: Mesh):
 
 _ACTIVE_MESH: Mesh | None = None
 
-# ---------------------------------------------------------------------------
-# Field-axis sharding for the batched NeurLZ compression engine.
-#
-# The engine stacks per-field enhancer params/slices on a leading "field"
-# axis (``repro.core.skipping_dnn.stack_params``); placing that axis on a
-# 1-D device mesh makes each device train its own subset of a snapshot's
-# fields — enhancers are independent, so no collectives are needed until the
-# host gathers trained weights for the archive.
-# ---------------------------------------------------------------------------
-
-FIELD_AXIS = "field"
-
-
-def field_mesh(devices=None) -> Mesh | None:
-    """1-D mesh over the field axis; ``None`` on a single-device process
-    (where sharding would only add dispatch overhead)."""
-    devs = list(devices) if devices is not None else jax.devices()
-    if len(devs) <= 1:
-        return None
-    return Mesh(np.array(devs), (FIELD_AXIS,))
-
-
-def field_sharding(mesh: Mesh, num_fields: int) -> NamedSharding:
-    """NamedSharding for a leading-``F``-axis array, guarded: a field count
-    that doesn't divide the mesh replicates instead of failing."""
-    ax = FIELD_AXIS if num_fields % _axis_size(mesh, FIELD_AXIS) == 0 else None
-    return NamedSharding(mesh, P(ax))
-
-
-def shard_fields(tree, mesh: Mesh):
-    """device_put every leading-``F``-axis leaf of a stacked pytree."""
-    def put(leaf):
-        return jax.device_put(leaf, field_sharding(mesh, leaf.shape[0]))
-    return jax.tree.map(put, tree)
-
-
 def set_active_mesh(mesh: Mesh | None):
     global _ACTIVE_MESH
     _ACTIVE_MESH = mesh

@@ -50,14 +50,26 @@ __all__ = [
 DEGRADABLE_EXCEPTIONS = (InjectedFault, MemoryError, FloatingPointError)
 
 
+# Markers of a *compile-time* RESOURCE_EXHAUSTED: a kernel or program the
+# compiler refused ("Ran out of memory in memory space vmem", Mosaic or
+# XLA:TPU compile errors).  Those are bugs in what the program asks the
+# device to run, identical on every retry and every field, so they abort.
+_COMPILE_MARKERS = ("compil", "mosaic", "vmem")
+
+
 def is_degradable(exc: BaseException) -> bool:
     """True when a per-field enhancer failure should degrade the field to
     conv-only instead of aborting the snapshot."""
     if isinstance(exc, DEGRADABLE_EXCEPTIONS):
         return True
-    # jax device OOM surfaces as XlaRuntimeError("RESOURCE_EXHAUSTED: ...")
-    # — matched by message so this package never imports jax.
-    return "RESOURCE_EXHAUSTED" in str(exc)
+    # Device memory exhausted at run time surfaces as a jax runtime error
+    # "RESOURCE_EXHAUSTED: ..." — matched by message so this package never
+    # imports jax.  The same status from the compiler is not degradable.
+    msg = str(exc)
+    if "RESOURCE_EXHAUSTED" not in msg:
+        return False
+    low = msg.lower()
+    return not any(m in low for m in _COMPILE_MARKERS)
 
 
 def degrade_reason(exc: BaseException | None = None) -> str:

@@ -11,7 +11,9 @@ predict/quantize) — selected by ``NeurLZConfig.lowering``:
   FMA-contraction suppressed (``jax.lax.optimization_barrier`` between the
   multiply and the add at every fused-multiply-add site), so the compiled
   path produces byte-identical archives.
-* ``pallas`` — the hand-written TPU kernels in this package.
+* ``pallas`` — the hand-written TPU kernels in this package (``lorenzo``
+  and ``fused_enhance``; ``dnn_forward`` has none, since it runs inside
+  the training step's gradient).
 * ``auto``   — pallas where supported, else jit, else eager.
 
 The contract is *verified, not assumed*: before a non-eager variant is used
@@ -19,8 +21,9 @@ it must pass its **parity probe** — a byte-for-byte comparison against the
 eager reference on canary inputs (including adversarial rounding-boundary
 values).  A variant that cannot honor the contract on this
 (backend, dtype, shape-class) falls back to eager, and the fallback is
-recorded (:func:`fallbacks`) so tests and telemetry can see it.  Probe
-verdicts are cached per (op, lowering, backend, probe-key).
+recorded (:func:`fallbacks`) so tests and telemetry can see it — a probe
+that returned a mismatch apart from one that raised.  Probe verdicts are
+cached per (op, lowering, backend, probe-key).
 
 Backend identification is a process-wide cached probe
 (:func:`backend`) instead of a per-call ``jax.default_backend()`` sniff;
@@ -88,8 +91,8 @@ class _Variant:
 
 # op name -> {lowering: _Variant}
 _ops: dict[str, dict[str, _Variant]] = {}
-# (op, lowering, backend, key) -> bool
-_verdicts: dict[tuple, bool] = {}
+# (op, lowering, backend, key) -> None (probe passed) | failure reason
+_verdicts: dict[tuple, str | None] = {}
 # (op, requested, chosen) -> count
 _resolutions: dict[tuple, int] = {}
 # [(op, lowering, backend, reason)] for every fallback decision
@@ -115,20 +118,42 @@ def register(op: str, lowering: str, fn, *, probe=None, backends=None) -> None:
             backends=tuple(backends) if backends is not None else None)
 
 
+MISMATCH = "parity probe mismatched"
+RAISED = "parity probe raised"
+
+
 def _probe_ok(op: str, lowering: str, var: _Variant, key=()) -> bool:
+    """Run (once per verdict key) and cache ``var``'s parity probe.
+
+    A probe that *returns* False is a byte mismatch — the honest fallback
+    the bit-stability contract allows.  A probe that *raises* means the
+    variant could not even run here (a kernel the compiler refused, a
+    missing lowering rule); it also falls back, but is recorded apart
+    (:data:`RAISED` plus the exception's type and first line) so a refused
+    kernel is never mistaken for a mismatch.
+    """
     if var.probe is None:
         return True
     vkey = (op, lowering, backend(), key)
     with _lock:
         if vkey in _verdicts:
-            return _verdicts[vkey]
+            return _verdicts[vkey] is None
     try:
-        ok = bool(var.probe())
-    except Exception:   # a variant that cannot even run cannot be bit-stable
-        ok = False
+        reason = None if var.probe() else MISMATCH
+    except Exception as exc:   # a variant that cannot even run cannot be bit-stable
+        first = (str(exc).strip().splitlines() or [""])[0]
+        reason = f"{RAISED} {type(exc).__name__}: {first}"
     with _lock:
-        _verdicts[vkey] = ok
-    return ok
+        _verdicts[vkey] = reason
+    if reason is not None:
+        _note_fallback(op, lowering, reason)
+    return reason is None
+
+
+def _verdict(op: str, lowering: str, key=()) -> str | None:
+    """The cached probe failure reason (None = passed or not probed)."""
+    with _lock:
+        return _verdicts.get((op, lowering, backend(), key))
 
 
 def resolve(op: str, lowering: str = "auto", *, key=()):
@@ -160,7 +185,6 @@ def resolve(op: str, lowering: str = "auto", *, key=()):
                 _note_fallback(op, cand, f"backend {backend()!r} unsupported")
             continue
         if not _probe_ok(op, cand, var, key):
-            _note_fallback(op, cand, "parity probe failed")
             continue
         _count(op, lowering, cand)
         return var.fn, cand
@@ -202,8 +226,11 @@ def clear_cache() -> None:
 def parity_report() -> dict:
     """Probe every registered non-eager variant on this backend.
 
-    ``{op: {lowering: "ok" | "fallback (<reason>)"}}`` — the local parity
-    check the README documents (`python -m repro.kernels.dispatch`).
+    ``{op: {lowering: "ok" | "unregistered" | "fallback (<reason>)"}}``
+    — the local parity check the README documents
+    (`python -m repro.kernels.dispatch`).  A probe that raised reads
+    ``fallback (parity probe raised <Type>: <first line>)``; a byte
+    mismatch reads ``fallback (parity probe mismatched)``.
     """
     report: dict = {}
     for op, variants in sorted(_ops.items()):
@@ -218,7 +245,7 @@ def parity_report() -> dict:
             elif _probe_ok(op, low, var):
                 report[op][low] = "ok"
             else:
-                report[op][low] = "fallback (parity probe failed)"
+                report[op][low] = f"fallback ({_verdict(op, low)})"
     return report
 
 

@@ -14,9 +14,11 @@ serves decode (orig := decomp makes the mask all-False and ``final`` the
 relaxed-mode enhancement).
 
 Tiling: elementwise over (rows, cols) tiles of the flattened-to-2D field;
-the row tile is sized to VMEM, with the last column dimension kept at the
-field's W (≤512) so tiles are lane-aligned (multiple of 128 for fp32 when W
-is — fields are 4³-padded upstream).
+the row tile is sized to VMEM by ops.py and is a multiple of 32 (the uint8
+mask's sublane tile), with the column dimension kept at the field's full W
+so any width is a legal block.  The index map returns ``np.int32``
+constants: under ``jax_enable_x64`` a bare Python ``0`` is an i64 block
+index, which Mosaic refuses.
 """
 from __future__ import annotations
 
@@ -24,7 +26,10 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
+
+_Z = np.int32(0)
 
 
 def _kernel(z_ref, dec_ref, orig_ref, out_ref, mask_ref, *, eb: float,
@@ -47,15 +52,16 @@ def _kernel(z_ref, dec_ref, orig_ref, out_ref, mask_ref, *, eb: float,
 
 @functools.partial(jax.jit, static_argnames=("eb", "regulated", "strict", "tr", "interpret"))
 def fused_enhance(z: jax.Array, decomp: jax.Array, orig: jax.Array, eb: float,
-                  *, regulated: bool = True, strict: bool = True, tr: int = 256,
-                  interpret: bool = True) -> tuple[jax.Array, jax.Array]:
+                  *, regulated: bool = True, strict: bool = True, tr: int,
+                  interpret: bool) -> tuple[jax.Array, jax.Array]:
     """All inputs (R, W) 2-D (ops.py reshapes/pads fields).  Returns
     (final same-dtype-as-decomp, outlier mask uint8)."""
     rows, cols = z.shape
-    assert rows % tr == 0, (rows, tr)
+    if rows % tr:
+        raise ValueError(f"{rows} rows are not a multiple of tr={tr}")
     kernel = functools.partial(_kernel, eb=float(eb), regulated=regulated,
                                strict=strict)
-    spec = pl.BlockSpec((tr, cols), lambda i: (i, 0))
+    spec = pl.BlockSpec((tr, cols), lambda i: (i, _Z))
     return pl.pallas_call(
         kernel,
         grid=(rows // tr,),

@@ -40,14 +40,3 @@ def fused_enhance_ref(z, decomp, orig, eb: float, *, regulated: bool = True,
     bad = jnp.abs(enh.astype(jnp.float32) - orig.astype(jnp.float32)) > eb
     out = jnp.where(bad, decomp, enh) if strict else enh
     return out, bad.astype(jnp.uint8)
-
-
-def conv2d3x3_ref(x, w, b, *, stride: int = 1, relu: bool = True):
-    y = jax.lax.conv_general_dilated(
-        x.astype(jnp.float32), w.astype(jnp.float32),
-        window_strides=(stride, stride), padding="SAME",
-        dimension_numbers=("NHWC", "HWIO", "NHWC"))
-    y = y + b.astype(jnp.float32)
-    if relu:
-        y = jnp.maximum(y, 0.0)
-    return y.astype(x.dtype)

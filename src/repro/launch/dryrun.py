@@ -224,12 +224,11 @@ def lower_neurlz_enhance(mesh, *, n_blocks: int = 512, side: int = 512,
     # Per-block training is embarrassingly parallel: shard_map over every
     # mesh axis pins the block dim per-device (plain pjit replicated the
     # conv activations -> 227 GiB/device, §Perf iteration C0->C1).
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     spec = P(every)
-    smapped = shard_map(train_step, mesh=mesh,
-                        in_specs=(spec, spec, spec, spec),
-                        out_specs=(spec, spec, P()), check_rep=False)
+    smapped = jax.shard_map(train_step, mesh=mesh,
+                            in_specs=(spec, spec, spec, spec),
+                            out_specs=(spec, spec, P()), check_vma=False)
 
     t0 = time.time()
     with mesh:

@@ -8,21 +8,14 @@ from __future__ import annotations
 import jax
 
 
-def mesh_kwargs(num_axes: int) -> dict:
-    """``axis_types`` kwarg for ``jax.make_mesh`` on jax versions that have
-    it (``jax.sharding.AxisType`` landed after 0.4.x); empty dict before."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * num_axes}
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **mesh_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh():
     """Single-device mesh for CPU smoke tests (model_axis=1)."""
-    return jax.make_mesh((1, 1), ("data", "model"), **mesh_kwargs(2))
+    return jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)

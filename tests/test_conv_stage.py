@@ -149,6 +149,41 @@ def test_stage_stats_group_accounting():
     assert st.calls == 3               # 1 fused + 2 singles < 6 fields
 
 
+@pytest.mark.parametrize("cap", [1, 2, 3])
+def test_stage_stacks_within_the_device_memory_cap(monkeypatch, cap):
+    """Where the device reports a memory limit, a same-signature group is
+    stacked only in runs that fit it (a 512³ group exhausts a 16 GiB chip);
+    payloads and reconstructions stay byte-identical to the uncapped run."""
+    fields = {f"f{i}": smooth_field((8, 10, 8), seed=i) for i in range(4)}
+    free = conv_stage.ConvStage("szlike", 1e-3).run(fields)
+    arcs = {n: a for n, (a, _) in free.items()}
+    monkeypatch.setattr(registry, "stack_limit", lambda points: cap)
+    stage = conv_stage.ConvStage("szlike", 1e-3)
+    capped = stage.run(fields)
+    for name in fields:
+        assert arc_io.dumps(capped[name][0]) == arc_io.dumps(arcs[name])
+        assert np.array_equal(capped[name][1], free[name][1])
+    runs = [min(cap, 4 - i) for i in range(0, 4, cap)]
+    assert stage.stats.calls == len(runs)
+    assert stage.stats.fallback_fields == runs.count(1)
+    widths = []
+
+    class Stats:
+        def note(self, w):
+            widths.append(w)
+
+    recs = registry.decompress_many(arcs, stats=Stats())
+    assert widths == runs
+    for name in fields:
+        assert np.array_equal(recs[name], free[name][1])
+
+
+def test_stack_limit_is_off_without_a_reported_limit():
+    # The CPU backend reports no memory limit: nothing is capped there.
+    assert registry.stack_limit(512 ** 3) is None
+    assert registry.stack_chunks(list("abc"), 512 ** 3) == [list("abc")]
+
+
 # ---------------------------------------------------------------------------
 # Engines x compressors byte-identity matrix
 # ---------------------------------------------------------------------------

@@ -1,4 +1,5 @@
 import numpy as np
+import pytest
 
 from repro.data import fields as F
 from repro.data.tokens import TokenStream
@@ -29,6 +30,25 @@ def test_coupling_zero_decorrelates():
     t = np.log(np.maximum(flds["temperature"].ravel(), 1e-9))
     d = np.log(np.maximum(flds["dark_matter_density"].ravel(), 1e-9))
     assert abs(np.corrcoef(t, d)[0, 1]) < 0.3
+
+
+@pytest.mark.parametrize("dataset", ["nyx", "miranda", "hurricane"])
+def test_field_subset_is_the_same_bytes(dataset):
+    # Generation stops after the last requested field; the fields come from
+    # one RNG stream, so a prefix or a single field matches the full block.
+    full = F.make_fields(dataset, shape=(6, 10, 9), seed=5)
+    names = list(full)
+    for k in range(1, len(names) + 1):
+        sub = F.make_fields(dataset, shape=(6, 10, 9), seed=5,
+                            names=names[:k])
+        assert list(sub) == names[:k]
+        assert all(sub[n].tobytes() == full[n].tobytes() for n in sub)
+    last = F.make_fields(dataset, shape=(6, 10, 9), seed=5,
+                         names=[names[-1]])
+    assert list(last) == [names[-1]]
+    assert last[names[-1]].tobytes() == full[names[-1]].tobytes()
+    with pytest.raises(ValueError, match="no fields"):
+        F.make_fields(dataset, shape=(6, 10, 9), names=["nope"])
 
 
 def test_token_stream_deterministic_replay():

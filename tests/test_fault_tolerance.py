@@ -123,6 +123,24 @@ def test_of_reads_config_attribute():
     assert of(object()) is DEFAULT
 
 
+@pytest.mark.parametrize("msg,degradable", [
+    # Device memory exhausted while running: degrade the field.
+    ("RESOURCE_EXHAUSTED: Error allocating device buffer: Attempting to "
+     "allocate 4.00G. That was not possible.", True),
+    ("RESOURCE_EXHAUSTED: Out of memory while trying to allocate 1.0G", True),
+    # The compiler refused the program or kernel: a bug, never degraded.
+    ("RESOURCE_EXHAUSTED: Ran out of memory in memory space vmem while "
+     "allocating on stack for %lorenzo3d_fwd.1", False),
+    ("RESOURCE_EXHAUSTED: XLA:TPU compile permanent error. Ran out of "
+     "memory in memory space hbm.", False),
+    ("INTERNAL: Mosaic failed to compile TPU kernel: failed to legalize "
+     "operation 'func.return'", False),
+    ("INTERNAL: something else entirely", False),
+])
+def test_compile_failures_are_not_degradable(msg, degradable):
+    assert is_degradable(RuntimeError(msg)) is degradable
+
+
 def test_degradability_classification():
     assert is_degradable(InjectedFault("s", 0))
     assert is_degradable(MemoryError())

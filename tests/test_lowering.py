@@ -42,7 +42,7 @@ def test_probe_failure_falls_back_and_is_recorded():
         fn, chosen = dispatch.resolve("_test_op", "jit")
         assert chosen == "eager" and fn() == "eager"
         assert ("_test_op", "jit", dispatch.backend(),
-                "parity probe failed") in dispatch.fallbacks()
+                dispatch.MISMATCH) in dispatch.fallbacks()
         # verdict is cached: a second resolve must not re-probe
         dispatch.resolve("_test_op", "jit")
         assert len(calls) == 1
@@ -53,13 +53,21 @@ def test_probe_failure_falls_back_and_is_recorded():
 
 def test_probe_exception_counts_as_failure():
     def boom():
-        raise RuntimeError("cannot even run")
+        raise RuntimeError("cannot even run\nsecond line")
 
     dispatch.register("_test_op2", "eager", lambda: "eager")
     dispatch.register("_test_op2", "pallas", lambda: "pallas", probe=boom)
     try:
         fn, chosen = dispatch.resolve("_test_op2", "pallas")
         assert chosen == "eager"
+        # Reported as raised (type + first line), never as a mismatch.
+        reason = f"{dispatch.RAISED} RuntimeError: cannot even run"
+        assert ("_test_op2", "pallas", dispatch.backend(),
+                reason) in dispatch.fallbacks()
+        assert not any(f[0] == "_test_op2" and f[3] == dispatch.MISMATCH
+                       for f in dispatch.fallbacks())
+        assert dispatch.parity_report()["_test_op2"]["pallas"] == \
+            f"fallback ({reason})"
     finally:
         dispatch._ops.pop("_test_op2", None)
         dispatch.clear_cache()
@@ -106,8 +114,11 @@ def test_tpu_gated_variants_fall_back_on_cpu():
     for op in ("dnn_forward", "lorenzo", "fused_enhance"):
         _, chosen = dispatch.resolve(op, "pallas")
         assert chosen == "eager", op
+    assert any(f[0] == "lorenzo" and f[1] == "pallas"
+               and "unsupported" in f[3] for f in dispatch.fallbacks())
+    # dnn_forward has no Pallas variant (it runs under value_and_grad).
     assert any(f[0] == "dnn_forward" and f[1] == "pallas"
-               for f in dispatch.fallbacks())
+               and f[3] == "unregistered" for f in dispatch.fallbacks())
 
 
 def test_parity_report_covers_all_ops():

@@ -59,17 +59,17 @@ def test_compressed_psum_under_shard_map():
 
     if jax.device_count() < 1:
         return
-    from repro.launch.mesh import mesh_kwargs
-    mesh = jax.make_mesh((1,), ("pod",), **mesh_kwargs(1))
+    mesh = jax.make_mesh((1,), ("pod",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     g = {"w": jnp.ones((8, 8), jnp.float32) * 0.5}
     ef = GC.init_ef(g)
 
     def f(g, ef):
         return GC.compressed_psum(g, ef, "pod", bits=8)
 
-    out, ef2 = jax.experimental.shard_map.shard_map(
+    out, ef2 = jax.shard_map(
         f, mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P()),
-        check_rep=False)(g, ef)
+        check_vma=False)(g, ef)
     np.testing.assert_allclose(np.asarray(out["w"]), 0.5, atol=0.01)
 
 

@@ -1,6 +1,8 @@
 """The paper's enhancer network: size claim, regulation range, ablations."""
 import jax
+import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.core import skipping_dnn as SD
 
@@ -53,3 +55,24 @@ def test_non_skipping_variant_runs():
     x = np.zeros((1, 32, 32, 1), np.float32)
     out = SD.forward(params, x, regulated=True, skip=False)
     assert out.shape == (1, 32, 32, 1)
+
+
+@pytest.mark.parametrize("hw", [(16, 16), (24, 20), (25, 33), (31, 17)])
+@pytest.mark.parametrize("cin,cout", [(1, 4), (4, 6), (8, 8), (12, 4),
+                                      (8, 1)])
+@pytest.mark.parametrize("stride", [1, 2])
+def test_conv_taps_match_xla_conv(hw, cin, cout, stride):
+    """Every conv layer of the forward is nine shifted HIGHEST-precision
+    GEMMs (C_out=1 padded to a two-column GEMM); across odd extents,
+    strides and channel counts it agrees with XLA's SAME conv."""
+    rng = np.random.default_rng(hash((hw, cin, cout, stride)) % 2**32)
+    x = jnp.asarray(rng.standard_normal((2, *hw, cin)), jnp.float32)
+    p = {"w": jnp.asarray(rng.standard_normal((3, 3, cin, cout)) * 0.2,
+                          jnp.float32),
+         "b": jnp.asarray(rng.standard_normal((cout,)) * 0.1, jnp.float32)}
+    y = SD._conv(x, p, stride=stride)
+    yr = jax.lax.conv_general_dilated(
+        x, p["w"], (stride, stride), "SAME", dimension_numbers=SD._DN,
+        precision=jax.lax.Precision.HIGHEST) + p["b"]
+    assert y.shape == yr.shape
+    assert np.allclose(np.asarray(y), np.asarray(yr), atol=1e-5)
