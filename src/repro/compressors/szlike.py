@@ -36,6 +36,7 @@ import numpy as np
 
 from . import codec, entropy
 from ..kernels import dispatch
+from ..obs import telemetry as obs
 from .quantize import CODE_CAP, abs_bound_from_rel
 
 _INTERNAL = jnp.float64 if jnp.array(0.0, jnp.float64).dtype == jnp.float64 else jnp.float32
@@ -95,6 +96,14 @@ def _encode_mask(mask: np.ndarray, level: int) -> dict:
     payload, cname = codec.compress(packed.tobytes(), level)
     return {"count": int(mask.size), "payload": payload, "codec": cname,
             "nbytes": len(payload)}
+
+
+def _encode_streams(codes, mask, lits, config: SZLikeConfig) -> dict:
+    """The archive's three host-coded streams: codes, escape mask,
+    literal escapes."""
+    return {"codes": entropy.encode_codes(codes, config.zstd_level),
+            "unpred": _encode_mask(mask, config.zstd_level),
+            "literals": entropy.encode_floats(lits, config.zstd_level)}
 
 
 def _decode_mask(blob: dict) -> np.ndarray:
@@ -168,8 +177,11 @@ def _interp_schedule(shape: tuple[int, ...], max_level: int) -> tuple[int, list]
 
 def _interp_run(x: jnp.ndarray, eb: float, level: int, phases, mean: float,
                 out_dtype=jnp.float32,
-                codes_in: list | None = None, masks_in=None, lits_in=None):
-    """Shared encode/decode walk.  Encode when ``codes_in is None``."""
+                codes_in: list | None = None, masks_in=None, lits_in=None,
+                tel=obs.NULL):
+    """Shared encode/decode walk.  Encode when ``codes_in is None``; each
+    encode phase reads its codes, escape mask and target values back to
+    the host (three reads counted on ``tel``)."""
     encode = codes_in is None
     # Coarsest lattice: predict the stored global mean.
     s0 = 1 << level
@@ -184,9 +196,10 @@ def _interp_run(x: jnp.ndarray, eb: float, level: int, phases, mean: float,
         nonlocal cursor, lit_cursor
         if encode:
             c, r, u = _quantize_phase(target_vals, pred, eb, out_dtype)
-            codes_out.append(np.asarray(c).ravel())
-            masks_out.append(np.asarray(u).ravel())
-            lits_out.append(np.asarray(target_vals)[np.asarray(u)].ravel())
+            un = obs.to_host(tel, u)
+            codes_out.append(obs.to_host(tel, c).ravel())
+            masks_out.append(un.ravel())
+            lits_out.append(obs.to_host(tel, target_vals)[un].ravel())
             return r
         n = int(np.prod(pred.shape))
         c = jnp.asarray(codes_in[cursor:cursor + n].reshape(pred.shape))
@@ -226,7 +239,8 @@ def _interp_run(x: jnp.ndarray, eb: float, level: int, phases, mean: float,
 
 
 def _interp_encode_batched(xs: jnp.ndarray, ebs: np.ndarray, level: int,
-                           phases, means: np.ndarray, out_dtype):
+                           phases, means: np.ndarray, out_dtype,
+                           tel=obs.NULL):
     """Stacked-``[F, ...]`` mirror of :func:`_interp_run`'s encode branch.
 
     Runs the *same eager op sequence* as the per-field path with a leading
@@ -238,6 +252,7 @@ def _interp_encode_batched(xs: jnp.ndarray, ebs: np.ndarray, level: int,
 
     Returns ``(rec [F, ...], [(codes, masks, lits)] per field)`` with the
     per-field streams concatenated in the per-field path's phase order.
+    Host reads (three per phase, then the reconstruction) count on ``tel``.
     """
     nf = xs.shape[0]
     fshape = xs.shape[1:]
@@ -251,9 +266,9 @@ def _interp_encode_batched(xs: jnp.ndarray, ebs: np.ndarray, level: int,
 
     def step(target_vals, pred):
         c, r, u = _quantize_phase(target_vals, pred, eb, out_dtype)
-        un = np.asarray(u)
-        vals = np.asarray(target_vals)
-        phase_codes.append(np.asarray(c))
+        un = obs.to_host(tel, u)
+        vals = obs.to_host(tel, target_vals)
+        phase_codes.append(obs.to_host(tel, c))
         phase_masks.append(un)
         # Extract each field's literal escapes now — retaining the full
         # target values until the end would pin an extra stacked-group copy.
@@ -286,7 +301,7 @@ def _interp_encode_batched(xs: jnp.ndarray, ebs: np.ndarray, level: int,
             np.concatenate(codes) if codes else np.zeros(0, np.int32),
             np.concatenate(masks) if masks else np.zeros(0, bool),
             np.concatenate(lits) if lits else np.zeros(0, x_dtype)))
-    return np.asarray(rec), streams
+    return obs.to_host(tel, rec), streams
 
 
 def _interp_decode_batched(pad_shape, ebs: np.ndarray, level: int, phases,
@@ -568,7 +583,8 @@ def _lorenzo_encode(stacked, eb_arr, out_dtype, lowering: str):
 
 def compress(x: np.ndarray, rel_eb: float | None = None, *, abs_eb: float | None = None,
              config: SZLikeConfig = SZLikeConfig(),
-             lowering: str = "auto") -> tuple[dict, np.ndarray]:
+             lowering: str = "auto",
+             telemetry=obs.NULL) -> tuple[dict, np.ndarray]:
     """Compress ``x``; returns ``(archive, reconstruction)``.
 
     The reconstruction is exactly what :func:`decompress` will produce —
@@ -579,7 +595,11 @@ def compress(x: np.ndarray, rel_eb: float | None = None, *, abs_eb: float | None
     variant that fails its parity probe falls back to eager).  The interp
     predictor is eager-only: its encode walks host-side entropy state
     between phases, so there is no jit variant to dispatch to.
+
+    ``telemetry`` times the phase walk (``interp``) and the host entropy
+    coding (``entropy``) and counts the transfers (:func:`obs.to_host`).
     """
+    tel = telemetry
     x = np.asarray(x)
     if x.ndim not in (2, 3):
         raise ValueError(f"expected 2-D or 3-D field, got shape {x.shape}")
@@ -597,39 +617,43 @@ def compress(x: np.ndarray, rel_eb: float | None = None, *, abs_eb: float | None
     if config.predictor == "interp":
         level, phases = _interp_schedule(work.shape, config.max_level)
         padded, orig_shape = _pad_to_lattice(work, level)
+        obs.count_h2d(tel, padded)
         xj = jnp.asarray(padded)
-        rec, (codes, masks, lits) = _interp_run(xj, eb_int, level, phases, mean,
-                                                out_dtype=jnp.dtype(orig_dtype))
-        rec_np = np.asarray(rec)[tuple(slice(0, d) for d in orig_shape)]
+        with tel.span("interp"):
+            rec, (codes, masks, lits) = _interp_run(
+                xj, eb_int, level, phases, mean,
+                out_dtype=jnp.dtype(orig_dtype), tel=tel)
+            rec_np = obs.to_host(tel, rec)[tuple(slice(0, d)
+                                                 for d in orig_shape)]
         arc = {
             "kind": "szlike", "predictor": "interp", "level": level,
             "shape": list(orig_shape), "pad_shape": list(padded.shape),
             "dtype": str(orig_dtype), "abs_eb": float(abs_eb), "eb_int": eb_int,
             "mean": mean,
-            "codes": entropy.encode_codes(codes, config.zstd_level),
-            "unpred": _encode_mask(masks, config.zstd_level),
-            "literals": entropy.encode_floats(lits, config.zstd_level),
         }
+        with tel.span("entropy"):
+            arc.update(_encode_streams(codes, masks, lits, config))
     elif config.predictor == "lorenzo":
         # One-field "group": the stacked [1, ...] op sequence is bitwise
         # the per-field one (elementwise ops; the size-1 leading axis is
         # skipped by the delta), which is the conv stage's byte-identity
         # contract — and it shares the dispatch-lowered encode.
+        obs.count_h2d(tel, work)
         xj = jnp.asarray(work)[None]
         eb_arr = jnp.asarray(
             np.asarray([eb_int], np.float64).reshape((1,) + (1,) * work.ndim))
         d, unpred, rec = _lorenzo_encode(xj, eb_arr, orig_dtype, lowering)
-        un_np = np.asarray(unpred)[0]
-        rec_np = np.asarray(rec)[0]
-        lits = work[un_np]
+        un_np = obs.to_host(tel, unpred)[0]
+        rec_np = obs.to_host(tel, rec)[0]
+        d_np = obs.to_host(tel, d)[0]
         arc = {
             "kind": "szlike", "predictor": "lorenzo",
             "shape": list(work.shape), "dtype": str(orig_dtype),
             "abs_eb": float(abs_eb), "eb_int": eb_int, "mean": mean,
-            "codes": entropy.encode_codes(np.asarray(d)[0], config.zstd_level),
-            "unpred": _encode_mask(un_np.ravel(), config.zstd_level),
-            "literals": entropy.encode_floats(lits, config.zstd_level),
         }
+        with tel.span("entropy"):
+            arc.update(_encode_streams(d_np, un_np.ravel(), work[un_np],
+                                       config))
     else:
         raise ValueError(f"unknown predictor {config.predictor!r}")
 
@@ -640,7 +664,7 @@ def compress(x: np.ndarray, rel_eb: float | None = None, *, abs_eb: float | None
 def compress_batched(xs, rel_eb: float | None = None, *,
                      abs_eb: float | None = None,
                      config: SZLikeConfig = SZLikeConfig(),
-                     lowering: str = "auto") -> list:
+                     lowering: str = "auto", telemetry=obs.NULL) -> list:
     """Compress a group of same-shape/same-dtype fields in one stacked pass.
 
     The conv-stage batched entry point: the group's whole quantize +
@@ -653,8 +677,10 @@ def compress_batched(xs, rel_eb: float | None = None, *,
 
     ``lowering`` routes the stacked Lorenzo quantize through
     :mod:`repro.kernels.dispatch` exactly as :func:`compress` does —
-    byte-identical payloads under every verdict.
+    byte-identical payloads under every verdict.  ``telemetry`` records
+    the same spans and transfer counts as :func:`compress`.
     """
+    tel = telemetry
     arrs = [np.asarray(x) for x in xs]
     if not arrs:
         return []
@@ -680,40 +706,44 @@ def compress_batched(xs, rel_eb: float | None = None, *,
     if config.predictor == "interp":
         level, phases = _interp_schedule(shape, config.max_level)
         padded = [_pad_to_lattice(w, level)[0] for w in works]
-        stacked = jnp.asarray(np.stack(padded))
-        recs, streams = _interp_encode_batched(
-            stacked, np.asarray(eb_ints), level, phases, np.asarray(means),
-            jnp.dtype(dtype))
+        host = np.stack(padded)
+        obs.count_h2d(tel, host)
+        stacked = jnp.asarray(host)
+        del host
+        with tel.span("interp"):
+            recs, streams = _interp_encode_batched(
+                stacked, np.asarray(eb_ints), level, phases,
+                np.asarray(means), jnp.dtype(dtype), tel=tel)
         crop = tuple(slice(0, d) for d in shape)
         for f in range(len(arrs)):
-            codes, masks, lits = streams[f]
             arc = {
                 "kind": "szlike", "predictor": "interp", "level": level,
                 "shape": list(shape), "pad_shape": list(padded[f].shape),
                 "dtype": str(dtype), "abs_eb": abs_ebs[f],
                 "eb_int": eb_ints[f], "mean": means[f],
-                "codes": entropy.encode_codes(codes, config.zstd_level),
-                "unpred": _encode_mask(masks, config.zstd_level),
-                "literals": entropy.encode_floats(lits, config.zstd_level),
             }
+            with tel.span("entropy"):
+                arc.update(_encode_streams(*streams[f], config))
             arc["nbytes"] = archive_nbytes(arc)
             out.append((arc, recs[f][crop].astype(dtype, copy=False)))
     elif config.predictor == "lorenzo":
-        stacked = jnp.asarray(np.stack(works))
+        host = np.stack(works)
+        obs.count_h2d(tel, host)
+        stacked = jnp.asarray(host)
+        del host
         bcast = (len(arrs),) + (1,) * len(shape)
         eb_arr = jnp.asarray(np.asarray(eb_ints, np.float64).reshape(bcast))
         d, unpred, rec = _lorenzo_encode(stacked, eb_arr, dtype, lowering)
-        d_np, un_np, rec_np = np.asarray(d), np.asarray(unpred), np.asarray(rec)
+        d_np, un_np, rec_np = (obs.to_host(tel, a) for a in (d, unpred, rec))
         for f in range(len(arrs)):
-            lits = works[f][un_np[f]]
             arc = {
                 "kind": "szlike", "predictor": "lorenzo",
                 "shape": list(shape), "dtype": str(dtype),
                 "abs_eb": abs_ebs[f], "eb_int": eb_ints[f], "mean": means[f],
-                "codes": entropy.encode_codes(d_np[f], config.zstd_level),
-                "unpred": _encode_mask(un_np[f].ravel(), config.zstd_level),
-                "literals": entropy.encode_floats(lits, config.zstd_level),
             }
+            with tel.span("entropy"):
+                arc.update(_encode_streams(d_np[f], un_np[f].ravel(),
+                                           works[f][un_np[f]], config))
             arc["nbytes"] = archive_nbytes(arc)
             out.append((arc, rec_np[f].astype(dtype, copy=False)))
     else:

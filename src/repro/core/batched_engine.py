@@ -262,8 +262,9 @@ class _GroupState:
 
 
 def _prepare_group(group: FieldGroup, fields, recs, ebs, config, tcfg,
-                   device=None) -> _GroupState:
-    """Host-side stage: datasets + async device upload + param init.
+                   device=None, tel=obs_lib.NULL) -> _GroupState:
+    """Host-side stage: datasets (``dataset`` spans) + async device upload
+    and param init (``upload`` spans).
 
     ``device`` pins the whole group (field sharding: groups are
     round-robined over devices, and jit runs each group's program where its
@@ -275,8 +276,9 @@ def _prepare_group(group: FieldGroup, fields, recs, ebs, config, tcfg,
     for name in group.names:
         x = np.asarray(fields[name])
         aux = [recs[a] for a in neurlz._aux_names(config, name, fields)]
-        inp, tgt, st = neurlz.build_dataset(x, recs[name], ebs[name], aux,
-                                            config)
+        with tel.span("dataset", field=name):
+            inp, tgt, st = neurlz.build_dataset(x, recs[name], ebs[name],
+                                                aux, config)
         n = inp.shape[0]
         b = min(tcfg.batch, n)
         s = max(1, n // b)
@@ -284,14 +286,17 @@ def _prepare_group(group: FieldGroup, fields, recs, ebs, config, tcfg,
         batches.append(b)
         totals.append(s * tcfg.epochs)
         # device_put is async: upload overlaps earlier groups' training.
-        inputs.append(jax.device_put(inp, device))
-        targets.append(jax.device_put(tgt, device))
+        with tel.span("upload", field=name):
+            obs_lib.count_h2d(tel, inp, tgt)
+            inputs.append(jax.device_put(inp, device))
+            targets.append(jax.device_put(tgt, device))
         stats.append(st)
-    key = jax.random.PRNGKey(tcfg.seed)
-    params = tuple(jax.device_put(skipping_dnn.init_params(key, net_cfg),
-                                  device)
-                   for _ in group.names)
-    opt = tuple(adamw_init(p) for p in params)
+    with tel.span("upload"):
+        key = jax.random.PRNGKey(tcfg.seed)
+        params = tuple(jax.device_put(skipping_dnn.init_params(key, net_cfg),
+                                      device)
+                       for _ in group.names)
+        opt = tuple(adamw_init(p) for p in params)
     return _GroupState(group=group, net_cfg=net_cfg, inputs=inputs,
                        targets=targets, stats=stats, params=params, opt=opt,
                        steps=steps, batch=batches, total_steps=totals)
@@ -443,15 +448,17 @@ def _dispatch_vmapped(state: _GroupState, tcfg, key) -> None:
                       for i in range(len(state.group.names)))
 
 
-def group_results(state: _GroupState):
-    """Sync point: block on the group's training/inference and yield
-    ``(f, name, history, resid)`` per field — shared by this engine's
-    finalize and the streaming pipeline's (which defers packing to the
-    writer thread)."""
-    history = np.asarray(state.losses)          # blocks on training
+def group_results(state: _GroupState, tel=obs_lib.NULL):
+    """Sync point: block on the group's training (``wait``) and fetch each
+    residual (``fetch``), yielding ``(f, name, history, resid)`` per field
+    — shared by this engine's finalize and the streaming pipeline's (which
+    defers packing to the writer thread)."""
+    with tel.span("wait"):
+        history = obs_lib.to_host(tel, state.losses)   # blocks on training
     for f, name in enumerate(state.group.names):
-        yield (f, name, [float(v) for v in history[:, f]],
-               np.asarray(state.resids[f]))
+        with tel.span("fetch", field=name):
+            resid = obs_lib.to_host(tel, state.resids[f])
+        yield f, name, [float(v) for v in history[:, f]], resid
 
 
 def _finalize_group(state: _GroupState, fields, recs, ebs, conv_arcs, config,
@@ -466,7 +473,7 @@ def _finalize_group(state: _GroupState, fields, recs, ebs, conv_arcs, config,
     instead of aborting the snapshot."""
     config = group_config(config, state.group)
     with tel.span("finalize", group=",".join(state.group.names)):
-        for f, name, hist, resid in group_results(state):
+        for f, name, hist, resid in group_results(state, tel):
             x = np.asarray(fields[name])
             aux_names = neurlz._aux_names(config, name, fields)
             entry, reason = None, None
@@ -475,12 +482,14 @@ def _finalize_group(state: _GroupState, fields, recs, ebs, conv_arcs, config,
                 if fc.degrade and not neurlz.history_is_finite(hist):
                     reason = faults_lib.degrade_reason()
                 else:
-                    entry = neurlz.pack_entry(
-                        config, conv_arcs[name], state.params[f],
-                        state.stats[f], aux_names, ebs[name], state.net_cfg,
-                        hist, collect_stats)
+                    with tel.span("pack", field=name):
+                        entry = neurlz.pack_entry(
+                            config, conv_arcs[name], state.params[f],
+                            state.stats[f], aux_names, ebs[name],
+                            state.net_cfg, hist, collect_stats)
                     neurlz.finalize_entry(entry, x, recs[name], resid,
-                                          ebs[name], state.stats[f], config)
+                                          ebs[name], state.stats[f], config,
+                                          tel=tel)
             except Exception as exc:
                 if not (fc.degrade and faults_lib.is_degradable(exc)):
                     raise
@@ -590,10 +599,14 @@ def compress(fields: Mapping[str, np.ndarray], rel_eb: float | None = None, *,
             conv_compress(group.names)
             dev = train_devs[gi % len(train_devs)] \
                 if config.field_shard and len(train_devs) > 1 else None
+            # Host prepare + enqueue only: training runs on the device
+            # after the span ends (its device time is in the profiler's
+            # trace; the host's block on it is ``finalize``'s ``wait``).
             with tel.span("train", group=",".join(group.names)) as sp:
                 state = _prepare_group(group, fields, recs, ebs, config,
-                                       tcfg, device=dev)
-                _dispatch_group(state, config, tcfg)   # async: no host sync
+                                       tcfg, device=dev, tel=tel)
+                with tel.span("dispatch"):
+                    _dispatch_group(state, config, tcfg)   # async
                 sp.set(devices=list(state.devices))
             states.append(state)
             if len(states) >= depth:

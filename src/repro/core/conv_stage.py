@@ -39,16 +39,17 @@ from ..compressors import registry
 from ..obs import telemetry as obs
 
 
-def _accepts_lowering(fn) -> bool:
-    """True iff ``fn`` takes a ``lowering`` kwarg (registry entries may wrap
-    third-party compressors that know nothing about kernel dispatch)."""
+def _declared_kwargs(fn, **kw) -> dict:
+    """The part of ``kw`` that ``fn`` declares (all of it when ``fn`` takes
+    ``**kwargs``): registry entries may wrap third-party compressors that
+    know nothing about kernel dispatch or telemetry."""
     try:
         params = inspect.signature(fn).parameters
     except (TypeError, ValueError):
-        return False
-    return ("lowering" in params
-            or any(p.kind is inspect.Parameter.VAR_KEYWORD
-                   for p in params.values()))
+        return {}
+    if any(p.kind is inspect.Parameter.VAR_KEYWORD for p in params.values()):
+        return kw
+    return {k: v for k, v in kw.items() if k in params}
 
 
 @dataclasses.dataclass
@@ -112,16 +113,17 @@ class ConvStage:
         # Per-field ErrorBound specs; fields absent here use the run scalars.
         self.bounds = dict(bounds) if bounds else None
         self.lowering = lowering
-        # The lowering request rides along only when the entry declares a
-        # ``lowering`` kwarg — third-party compressor entries are untouched.
-        self._lower_kw = ({"lowering": lowering}
-                          if _accepts_lowering(self.entry.compress) else {})
-        self._lower_kw_batched = (
-            {"lowering": lowering}
-            if (self.entry.compress_batched is not None
-                and _accepts_lowering(self.entry.compress_batched)) else {})
         self.stats = ConvStats(lowering=lowering)
         self.tel = telemetry if telemetry is not None else obs.NULL
+        # The lowering request and the telemetry handle ride along only
+        # when the entry declares the kwarg — third-party compressor entries
+        # are untouched.
+        self._kw = _declared_kwargs(self.entry.compress, lowering=lowering,
+                                    telemetry=self.tel)
+        self._kw_batched = (
+            _declared_kwargs(self.entry.compress_batched, lowering=lowering,
+                             telemetry=self.tel)
+            if self.entry.compress_batched is not None else {})
 
     def bound_for(self, name: str) -> tuple[float | None, float | None]:
         """``(rel_eb, abs_eb)`` this run will hand the compressor for one
@@ -160,7 +162,6 @@ class ConvStage:
             for group in self.plan(metas):
                 self.stats.groups += 1
                 tel.counter("conv.groups").add()
-                tel.gauge("conv.group_size").set(len(group))
                 dtype = metas[group[0]][1]
                 rel, ab = self.bound_for(group[0])  # one spec/group, by plan
                 stackable = (batch and len(group) > 1
@@ -172,21 +173,21 @@ class ConvStage:
                     if len(chunk) > 1:
                         results = self.entry.compress_batched(
                             [arrs[n] for n in chunk], rel, abs_eb=ab,
-                            **self._lower_kw_batched)
+                            **self._kw_batched)
                         self.stats.calls += 1
                         self.stats.batched_fields += len(chunk)
-                        self.stats.lowered_calls += bool(
-                            self._lower_kw_batched)
+                        self.stats.lowered_calls += \
+                            "lowering" in self._kw_batched
                         tel.counter("conv.dispatches").add()
                         tel.counter("conv.batched_fields").add(len(chunk))
                         out.update(zip(chunk, results))
                         continue
                     for n in chunk:
                         out[n] = self.entry.compress(arrs[n], rel, abs_eb=ab,
-                                                     **self._lower_kw)
+                                                     **self._kw)
                         self.stats.calls += 1
                         self.stats.fallback_fields += 1
-                        self.stats.lowered_calls += bool(self._lower_kw)
+                        self.stats.lowered_calls += "lowering" in self._kw
                         tel.counter("conv.dispatches").add()
                         tel.counter("conv.fallback_fields").add()
             sp.set(calls=self.stats.calls - calls0)

@@ -211,11 +211,15 @@ def enhance_and_mask(x: np.ndarray, rec: np.ndarray, resid_norm: np.ndarray,
 
 def finalize_entry(entry: dict, x: np.ndarray, rec: np.ndarray,
                    resid_norm: np.ndarray, eb: float, stats,
-                   config: NeurLZConfig) -> np.ndarray:
-    """Enhancement + strict-mode outlier capture; mutates ``entry``."""
-    field_rec, mask = enhance_and_mask(x, rec, resid_norm, eb, stats, config)
+                   config: NeurLZConfig, tel=obs_lib.NULL) -> np.ndarray:
+    """Enhancement (``enhance`` span) + strict-mode outlier capture
+    (``outliers`` span); mutates ``entry``."""
+    with tel.span("enhance"):
+        field_rec, mask = enhance_and_mask(x, rec, resid_norm, eb, stats,
+                                           config)
     if mask is not None:
-        entry["outliers"] = outlier_codec.encode_outliers(mask)
+        with tel.span("outliers"):
+            entry["outliers"] = outlier_codec.encode_outliers(mask)
     return field_rec
 
 

@@ -23,12 +23,14 @@ never flips the x64 switch or pays an engine import.
 """
 from .telemetry import (NULL, TIMING_KEYS, Counter, Gauge,  # noqa: F401
                         NullTelemetry, SpanRecord, Telemetry,
-                        TelemetryConfig, build_timing, learning_trace, of)
+                        TelemetryConfig, build_timing, count_h2d,
+                        learning_trace, of, to_host)
 from .export import (chrome_trace, summary, write_chrome_trace,  # noqa: F401
                      write_jsonl)
 
 __all__ = [
     "Telemetry", "TelemetryConfig", "NullTelemetry", "NULL", "of",
+    "to_host", "count_h2d",
     "Counter", "Gauge", "SpanRecord", "TIMING_KEYS",
     "build_timing", "learning_trace",
     "write_jsonl", "chrome_trace", "write_chrome_trace", "summary",

@@ -25,6 +25,11 @@ the same surface with shared no-op span/counter/gauge objects, so
 call and nothing else.  Engines obtain their handle with :func:`of`, which
 maps ``config.telemetry is None`` to :data:`NULL`.
 
+Host<->device transfers on the compress path are counted where they
+happen: :func:`to_host` is the one blocking device->host read
+(``xfer.d2h_syncs``, ``xfer.d2h_bytes``) and :func:`count_h2d` counts host
+arrays handed to the device (``xfer.h2d_bytes``).
+
 This module deliberately imports neither jax nor any ``repro`` subpackage,
 so constructing a :class:`Telemetry` never flips the x64 switch.
 """
@@ -36,10 +41,12 @@ import threading
 import time
 from typing import Any
 
+import numpy as np
+
 __all__ = [
     "Telemetry", "TelemetryConfig", "SpanRecord", "Counter", "Gauge",
-    "NullTelemetry", "NULL", "of", "build_timing", "learning_trace",
-    "TIMING_KEYS",
+    "NullTelemetry", "NULL", "of", "to_host", "count_h2d", "build_timing",
+    "learning_trace", "TIMING_KEYS",
 ]
 
 
@@ -194,6 +201,12 @@ class Telemetry:
         self._root_id: int | None = None
         self._local = threading.local()
         self.dropped_spans = 0
+
+    @property
+    def perf0(self) -> float:
+        """``time.perf_counter()`` at construction: span ``t0`` values are
+        seconds after it."""
+        return self._perf0
 
     # -- internals ----------------------------------------------------------
 
@@ -409,6 +422,21 @@ def of(config) -> Telemetry | NullTelemetry:
     attribute), or :data:`NULL`."""
     tel = getattr(config, "telemetry", None)
     return tel if tel is not None else NULL
+
+
+def to_host(tel, x) -> np.ndarray:
+    """``np.asarray(x)`` as one blocking device->host read, counted under
+    ``xfer.d2h_syncs`` and ``xfer.d2h_bytes`` (the host array's bytes)."""
+    out = np.asarray(x)
+    tel.counter("xfer.d2h_syncs").add()
+    tel.counter("xfer.d2h_bytes").add(out.nbytes)
+    return out
+
+
+def count_h2d(tel, *host_arrays) -> None:
+    """Count host arrays about to be copied to the device under
+    ``xfer.h2d_bytes``."""
+    tel.counter("xfer.h2d_bytes").add(sum(a.nbytes for a in host_arrays))
 
 
 # ---------------------------------------------------------------------------

@@ -440,7 +440,7 @@ def compress(source, sink, rel_eb: float | None = None, *,
             gcfg = batched_engine.group_config(config, state.group)
             with tel.span("retire", group=",".join(state.group.names)):
                 for f, name, hist, resid in \
-                        batched_engine.group_results(state):
+                        batched_engine.group_results(state, tel):
                     x = np.asarray(xs[name])
                     reason, mask = None, None
                     try:
@@ -543,7 +543,7 @@ def compress(source, sink, rel_eb: float | None = None, *,
                             group,
                             _SnapshotView({n: xs[n] for n in group.names},
                                           names),
-                            recs, ebs, config, tcfg)
+                            recs, ebs, config, tcfg, tel=tel)
                         batched_engine._dispatch_group(state, config, tcfg)
                 in_flight.append(state)
                 # Retire down to depth BEFORE prefetching: steady-state

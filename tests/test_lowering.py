@@ -167,9 +167,11 @@ def test_fused_enhance_lowered_bytes_match_eager():
 # ---------------------------------------------------------------------------
 
 def test_accepts_lowering_signature_inspection():
-    assert conv_stage._accepts_lowering(lambda x, *, lowering="auto": x)
-    assert conv_stage._accepts_lowering(lambda x, **kw: x)
-    assert not conv_stage._accepts_lowering(lambda x, rel_eb: x)
+    kw = {"lowering": "jit", "telemetry": None}
+    assert conv_stage._declared_kwargs(
+        lambda x, *, lowering="auto": x, **kw) == {"lowering": "jit"}
+    assert conv_stage._declared_kwargs(lambda x, **k: x, **kw) == kw
+    assert conv_stage._declared_kwargs(lambda x, rel_eb: x, **kw) == {}
 
 
 @pytest.mark.parametrize("compressor", ["szlike", "szlike-lorenzo",

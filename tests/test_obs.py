@@ -287,6 +287,19 @@ def test_span_cap_drops_not_grows():
     assert tel.dropped_spans == 7
 
 
+def test_perf0_puts_spans_on_the_perf_counter_clock():
+    import time
+    tel = obs.Telemetry()
+    before = time.perf_counter()
+    with tel.span("s"):
+        pass
+    after = time.perf_counter()
+    (sp,) = tel.spans
+    assert before <= tel.perf0 + sp.t0 <= tel.perf0 + sp.t0 + sp.dur <= after
+    with pytest.raises(AttributeError):
+        tel.perf0 = 0.0
+
+
 def test_of_maps_none_to_null():
     cfg = neurlz.NeurLZConfig()
     assert obs.of(cfg) is obs.NULL
@@ -310,3 +323,132 @@ def test_session_api_threads_telemetry(tmp_path):
         assert arc2.telemetry is tel2
         arc2.decode("f0")
         assert tel2.counters["archive.entry_reads"] >= 1
+
+
+# ---------------------------------------------------------------------------
+# Host phases: leaf spans and host<->device transfer counters
+# ---------------------------------------------------------------------------
+
+# Leaf span -> the stage span it nests in.  The streaming engine shares the
+# conventional stage and the batched engine's prepare/results helpers; its
+# ``retire`` takes the place of ``finalize``.
+PHASES = {
+    "batched": {"dataset": "train", "upload": "train", "dispatch": "train",
+                "wait": "finalize", "fetch": "finalize",
+                "enhance": "finalize", "outliers": "finalize",
+                "pack": "finalize", "interp": "conv", "entropy": "conv"},
+    "streaming": {"dataset": "train", "upload": "train", "wait": "retire",
+                  "fetch": "retire", "interp": "conv", "entropy": "conv"},
+}
+
+
+@pytest.mark.parametrize("engine", sorted(PHASES))
+def test_phase_spans_nest_in_their_stage(runs, engine):
+    tel, _, _ = runs[engine]
+    by_id = {s.id: s for s in tel.spans}
+    parents: dict = {}
+    for s in tel.spans:
+        if s.name in PHASES[engine]:
+            parents.setdefault(s.name, set()).add(by_id[s.parent].name)
+    assert parents == {n: {p} for n, p in PHASES[engine].items()}
+
+
+def _expected_transfers(engine) -> dict:
+    """The transfer counters of one ``_run`` from the shapes alone.
+
+    Training groups hold ``group_size`` fields and the conventional stage
+    runs once per group (one stacked call).  Its encode reads codes
+    (int32), escape mask (bool) and target values (f64) at the initial
+    lattice and at every phase of ``_interp_schedule``, then the f64
+    reconstruction; every point of the lattice-padded field is a target
+    exactly once.  Each group then reads its losses (f32 [epochs, F]) and
+    one f32 residual per field, after uploading f64 padded fields to the
+    conventional stage and f32 single-channel inputs and targets."""
+    from repro.compressors import szlike
+    cfg = neurlz.NeurLZConfig(engine=engine, epochs=EPOCHS)
+    shape = FIELDS["f0"].shape
+    level, phases = szlike._interp_schedule(shape,
+                                            szlike.SZLikeConfig().max_level)
+    s = 1 << level
+    padded = int(np.prod([d if d == 1 else -(-(d - 1) // s) * s + 1
+                          for d in shape]))
+    size = int(np.prod(shape))
+    names = list(FIELDS)
+    syncs = d2h = h2d = 0
+    for i in range(0, len(names), cfg.group_size):
+        f = len(names[i:i + cfg.group_size])
+        syncs += 3 * (1 + len(phases)) + 1 + 1 + f
+        d2h += f * padded * (4 + 1 + 8 + 8) + EPOCHS * f * 4 + f * size * 4
+        h2d += f * padded * 8 + f * 2 * size * 4
+    return {"xfer.d2h_syncs": syncs, "xfer.d2h_bytes": d2h,
+            "xfer.h2d_bytes": h2d}
+
+
+@pytest.mark.parametrize("engine", sorted(PHASES))
+def test_transfer_counters_match_the_shapes(runs, engine):
+    tel, _, _ = runs[engine]
+    got = tel.counters_prefixed("xfer.")
+    assert got == _expected_transfers(engine)
+
+
+@pytest.mark.parametrize("batched", [False, True])
+@pytest.mark.parametrize("predictor", ["interp", "lorenzo"])
+def test_conv_entry_archives_identical_with_telemetry(predictor, batched):
+    import pickle
+    from repro.compressors import szlike
+    cfg = szlike.SZLikeConfig(predictor=predictor)
+    xs = [FIELDS["f0"], FIELDS["f1"]]
+
+    def go(tel):
+        if batched:
+            return szlike.compress_batched(xs, 1e-3, config=cfg,
+                                           telemetry=tel)
+        return [szlike.compress(x, 1e-3, config=cfg, telemetry=tel)
+                for x in xs]
+
+    tel = obs.Telemetry()
+    on, off = go(tel), go(obs.NULL)
+    for (arc, rec), (arc0, rec0) in zip(on, off):
+        assert pickle.dumps(arc) == pickle.dumps(arc0)
+        assert rec.tobytes() == rec0.tobytes()
+    calls = 1 if batched else len(xs)
+    names = [s.name for s in tel.spans]
+    assert names.count("entropy") == len(xs)
+    assert names.count("interp") == (calls if predictor == "interp" else 0)
+    _, phases = szlike._interp_schedule(xs[0].shape, cfg.max_level)
+    per_call = 3 * (1 + len(phases)) + 1 if predictor == "interp" else 3
+    assert tel.counters["xfer.d2h_syncs"] == calls * per_call
+
+
+def test_entry_without_telemetry_kwarg_runs_through_conv_stage():
+    """A registry entry that declares no ``telemetry`` keyword gets no
+    handle; the stage still times and counts it."""
+    import pickle
+    from repro.compressors import registry, szlike
+    from repro.core import conv_stage
+
+    def plain(x, rel_eb=None, *, abs_eb=None):
+        return szlike.compress(x, rel_eb, abs_eb=abs_eb)
+
+    def plain_batched(xs, rel_eb=None, *, abs_eb=None):
+        return szlike.compress_batched(xs, rel_eb, abs_eb=abs_eb)
+
+    registry.register(registry.CompressorEntry(
+        name="szlike-plain", kind="szlike", compress=plain,
+        decompress=szlike.decompress, archive_nbytes=szlike.archive_nbytes,
+        compress_batched=plain_batched,
+        decompress_batched=szlike.decompress_batched,
+        decode_key=szlike.decode_key))
+    try:
+        tel = obs.Telemetry()
+        stage = conv_stage.ConvStage("szlike-plain", 1e-3, telemetry=tel)
+        out = stage.run(FIELDS)
+    finally:
+        registry.unregister("szlike-plain")
+    ref = conv_stage.ConvStage("szlike", 1e-3).run(FIELDS)
+    for n in FIELDS:
+        assert pickle.dumps(out[n][0]) == pickle.dumps(ref[n][0])
+    assert stage.stats.calls == 1 and stage.stats.lowered_calls == 0
+    assert {s.name for s in tel.spans} == {"conv"}
+    assert tel.counters_prefixed("xfer.") == {}
+    assert "conv.group_size" not in tel.gauges
