@@ -37,8 +37,8 @@ def _time_best(f, *args, n=10, repeats=5):
 
 
 def _dnn_forward_row(full: bool, smoke: bool):
-    """PR 9 hot-loop row: the bit-stable GEMM-tap forward vs the historical
-    XLA-conv formulation (``forward_reference``, the PR 8 baseline path).
+    """Hot-loop row: the bit-stable multiply-and-sum tap forward vs the
+    historical XLA-conv formulation (``forward_reference``).
     Both are jitted and timed in-process, so the ratio is a same-machine
     comparison; the smoke profile **fails** below the 2x gate."""
     from repro.core import skipping_dnn as sd

@@ -22,14 +22,14 @@ resident together), so this engine restructures the hot path around the
   * **``field_batching="vmap"``** — per-field params are stacked on a
     leading ``F`` axis (:func:`repro.core.skipping_dnn.stack_params`) and
     each epoch runs as one ``jax.vmap``-over-fields ``lax.scan``.  The
-    skipping-DNN forward is built from
-    shift-and-accumulate ``lax.dot_general`` contractions that lower
-    identically under ``vmap`` (see :mod:`repro.core.skipping_dnn`), so
-    equal-slice-count groups are bit-identical to serial at most training
-    signatures (XLA:CPU can still partition a gradient GEMM differently
-    at some sizes); ragged fields train the padded step count per epoch
-    with modulo-resampled slices and diverge from the serial trajectory
-    (error-bound guarantees are unaffected either way).
+    skipping-DNN forward is built from channels-first multiply-and-sum
+    taps that lower identically under ``vmap`` (see
+    :mod:`repro.core.skipping_dnn`), so equal-slice-count groups are
+    bit-identical to serial at most training signatures (a backend can
+    still split a gradient reduction differently at some sizes); ragged
+    fields train the padded step count per epoch with modulo-resampled
+    slices and diverge from the serial trajectory (error-bound guarantees
+    are unaffected either way).
   * **``field_batching="auto"`` (default)** — per group: the stacked
     ``vmap`` path for multi-field groups with matching slice counts,
     *verified* by a cached per-signature byte-parity probe
@@ -328,10 +328,11 @@ def vmap_bit_parity(net_cfg, slice_hw: tuple, batch: int, tcfg) -> bool:
     """Byte-parity probe for the stacked vmap strategy at one training
     signature.
 
-    The fast shift-and-accumulate forward lowers identically under
-    ``jax.vmap`` for most shapes, but XLA:CPU may partition a *gradient*
-    contraction differently between the single and the batched GEMM at
-    some sizes, reassociating the reduction.  Lowered code is
+    The forward's multiply-and-sum taps keep one reduction per layer with
+    or without the field axis, but a backend may still split a *gradient*
+    reduction (over the batch and the plane) differently between the
+    single and the batched program at some sizes, reassociating it (the
+    TPU does at the 512² cell signature).  Lowered code is
     shape-dependent, not value-dependent, so one byte-compare of
     ``value_and_grad`` on canary inputs — per (spatial, channels, batch,
     loss) signature, cached — decides whether the stacked path is
@@ -373,8 +374,9 @@ def _device_ids(x) -> tuple:
     return tuple(sorted(d.id for d in x.devices()))
 
 
-def _dispatch_group(state: _GroupState, config, tcfg) -> None:
-    """Enqueue the group's full training AND inference without blocking."""
+def _dispatch_group(state: _GroupState, config, tcfg) -> str:
+    """Enqueue the group's full training AND inference without blocking;
+    returns the batching strategy that ran (``"vmap"`` or ``"unroll"``)."""
     net_cfg = state.net_cfg
     key = jax.random.PRNGKey(tcfg.seed)
     strategy = resolve_batching(config.field_batching,
@@ -409,6 +411,7 @@ def _dispatch_group(state: _GroupState, config, tcfg) -> None:
                   for _ in state.group.names)
     state.resids = _predict_group(tuple(state.params), tuple(state.inputs),
                                   spec=pspec, lowering=tcfg.lowering)
+    return strategy
 
 
 def _dispatch_vmapped(state: _GroupState, tcfg, key) -> None:
@@ -605,8 +608,8 @@ def compress(fields: Mapping[str, np.ndarray], rel_eb: float | None = None, *,
             with tel.span("train", group=",".join(group.names)) as sp:
                 state = _prepare_group(group, fields, recs, ebs, config,
                                        tcfg, device=dev, tel=tel)
-                with tel.span("dispatch"):
-                    _dispatch_group(state, config, tcfg)   # async
+                with tel.span("dispatch") as dsp:   # async enqueue
+                    dsp.set(batching=_dispatch_group(state, config, tcfg))
                 sp.set(devices=list(state.devices))
             states.append(state)
             if len(states) >= depth:

@@ -16,20 +16,36 @@ Output heads (§3.3.2, Fig. 6):
 
 Forward formulation (the bit-stable fast path)
 ----------------------------------------------
-These convs are XLA's worst case: 3×3 kernels over 1–16 channels lower to
-``conv_general_dilated`` programs that run ~3 GFLOP/s on CPU.  The forward
-here instead expresses every conv as an accumulation of nine shifted
-``jax.lax.dot_general`` contractions (one GEMM per kernel tap) and every
-stride-2 transpose conv as its sub-pixel decomposition — four parity planes,
-each a small accumulation of taps on the un-dilated grid, interleaved back.
-All contractions are pinned to ``precision=HIGHEST``, additions happen in a
-fixed tap order, and single-output-channel convs are padded to two columns
-(a ``(K, 1)`` GEMV re-associates under ``vmap`` where a ``(K, 2)`` GEMM does
-not), which makes the forward **byte-identical** under eager, ``jit``,
-``vmap``-over-fields and grad — the property the batched engine's stacked
-strategy and the conv-stage jit path rely on (tests/test_lowering.py).
-It is also 2–3× faster than the XLA conv lowering on CPU (bench_kernels
-``kernel/dnn_forward`` row).
+3×3 kernels over 1–16 channels are a poor fit for both of XLA's usual
+lowerings.  Channels-last, the 1–16 channels sit on the TPU's 128 lanes
+(8–128× padding) and every tap is a GEMM with K and N ≤ 16.  So the forward
+runs channels-first, ``[C, N, H, W]``: one transpose in, one out, and the
+plane's width sits on the lanes and its height on the sublanes.  Every conv
+stacks its nine shifted windows on the channel axis in a fixed tap order
+and contracts them in one f32 broadcast multiply and sum (:func:`_dot`);
+every stride-2 transpose conv is its sub-pixel decomposition — four parity
+planes, each one such contraction of its taps on the un-dilated grid.
+Products and sums are plain f32 (no MXU pass, nothing in bfloat16), so the
+result is at least as exact as a ``precision=HIGHEST`` GEMM, and one
+reduction per layer in a fixed tap order makes the forward
+**byte-identical** under eager, ``jit`` and grad, and under
+``vmap``-over-fields wherever the backend lowers the gradient's reductions
+alike with and without the field axis — the property the batched engine's
+stacked strategy and the conv-stage jit path rely on
+(tests/test_lowering.py, tests/test_skipping_dnn.py); the stacked strategy
+is still gated by :func:`~repro.core.batched_engine.vmap_bit_parity`,
+which the CPU passes and a v5e fails at 512² slices.  On a v5e one
+training step at ``[10, 512, 512, 2]`` took 36.7 ms with these stacked taps
+(cut as strided windows, before the parity split below) against 99.6 ms
+with the channels-last ``HIGHEST`` GEMM taps they replaced.
+
+Channels-first, a stride-2 window and a parity interleave are lane
+shuffles, which the TPU does far below its memory bandwidth (a strided
+window of a 512² map took ~0.8 ms on a v5e).  So a stride-2 conv splits its
+padded input into parity planes once (:func:`_split`) and reads each of
+its nine windows as a unit-stride slice of one plane, and a transpose conv
+returns its four parity planes for the caller to interleave once
+(:func:`_merge`).
 
 The historical XLA formulation is kept as :func:`forward_reference` — the
 accuracy oracle and the perf baseline; it is *not* bit-identical to
@@ -47,8 +63,7 @@ import numpy as np
 
 from ..kernels import dispatch
 
-_DN = ("NHWC", "HWIO", "NHWC")
-_P = jax.lax.Precision.HIGHEST
+_DN = ("CNHW", "HWIO", "CNHW")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -114,82 +129,92 @@ def unstack_params(stacked, num_fields: int):
 
 
 # ---------------------------------------------------------------------------
-# Fast bit-stable formulation: convs as accumulated shifted GEMMs
+# Fast bit-stable formulation: channels-first multiply-and-sum taps
 # ---------------------------------------------------------------------------
 
 def _dot(a, w):
-    """Contract ``a``'s channel axis with ``w [cin, cout]``; one GEMM,
-    precision pinned so the reduction is never FMA-contracted or split."""
-    return jax.lax.dot_general(a, w, (((a.ndim - 1,), (0,)), ((), ())),
-                               precision=_P)
+    """Contract ``a``'s last axis with ``w``'s first: weights ``[C_out, K]``
+    applied to stacked tap windows ``[K, N, H, W]`` give ``[C_out, N, H, W]``.
+    Exact f32 products summed over the ``K`` axis in one reduction — no MXU
+    pass, nothing in bfloat16."""
+    a = a.reshape(a.shape + (1,) * (w.ndim - 1))
+    return jnp.sum(a * w, axis=a.ndim - w.ndim)
 
 
-def _conv_taps(x, w, b, stride):
-    """SAME 3×3 conv as nine shifted ``_dot`` accumulations, fixed tap order."""
-    n, h, wd, cin = x.shape
+def _split(x):
+    """``[C, N, H, W]`` -> its four parity planes ``[C, 4, N, H/2, W/2]``,
+    plane ``2*py + px`` holding ``x[..., py::2, px::2]``."""
+    c, n, h, w = x.shape
+    x = x.reshape(c, n, h // 2, 2, w // 2, 2)
+    return jnp.transpose(x, (0, 3, 5, 1, 2, 4)).reshape(c, 4, n, h // 2,
+                                                        w // 2)
+
+
+def _merge(x):
+    """Inverse of :func:`_split`."""
+    c, _, n, h, w = x.shape
+    x = jnp.transpose(x.reshape(c, 2, 2, n, h, w), (0, 3, 4, 1, 5, 2))
+    return x.reshape(c, n, 2 * h, 2 * w)
+
+
+def _conv(x, p, stride=1):
+    """SAME 3×3 conv over ``x [C, N, H, W]``: the nine shifted windows
+    stacked on the channel axis in a fixed tap order (``dy``, ``dx``,
+    ``C_in``), one ``_dot``.  A stride-2 conv splits its padded input into
+    parity planes once (:func:`_split`), so each window is a unit-stride
+    slice of one plane (tap ``dy`` reads parity ``dy % 2`` from row
+    ``dy // 2``) rather than a strided slice of the lanes."""
+    w, b = p["w"], p["b"]
+    cin, n, h, wd = x.shape
+    cout = w.shape[-1]
     ho = (h + stride - 1) // stride
     wo = (wd + stride - 1) // stride
 
     def pads(size, out):
-        total = max((out - 1) * stride + 3 - size, 0)
-        lo = total // 2
-        return lo, total - lo
+        lo = max((out - 1) * stride + 3 - size, 0) // 2
+        # stride 2 reads 2 * out + 1 rows: one more makes the count even
+        return lo, (out + 2 if stride == 1 else 2 * out + 2) - size - lo
 
-    ylo, yhi = pads(h, ho)
-    xlo, xhi = pads(wd, wo)
-    xp = jnp.pad(x, ((0, 0), (ylo, yhi), (xlo, xhi), (0, 0)))
-    acc = None
-    for dy in range(3):
-        for dx in range(3):
-            win = jax.lax.slice(
-                xp, (0, dy, dx, 0),
-                (n, dy + (ho - 1) * stride + 1, dx + (wo - 1) * stride + 1,
-                 cin),
-                (1, stride, stride, 1))
-            t = _dot(win, w[dy, dx])
-            acc = t if acc is None else acc + t
-    return acc + b
-
-
-def _conv(x, p, stride=1):
-    w, b = p["w"], p["b"]
-    cout = w.shape[-1]
-    if cout == 1:
-        # A (K, 1) contraction lowers to a GEMV whose batched form under
-        # vmap re-associates the reduction; a zero-padded (K, 2) GEMM lowers
-        # identically in both — the sole source of vmap bit-divergence.
-        w = jnp.concatenate([w, jnp.zeros_like(w)], axis=-1)
-        b = jnp.concatenate([b, jnp.zeros_like(b)])
-        return _conv_taps(x, w, b, stride)[..., :1]
-    return _conv_taps(x, w, b, stride)
+    xp = jnp.pad(x, ((0, 0), (0, 0), pads(h, ho), pads(wd, wo)))
+    taps = [(dy, dx) for dy in range(3) for dx in range(3)]
+    if stride == 2:
+        xp = _split(xp)
+        wins = [xp[:, 2 * (dy % 2) + dx % 2, :, dy // 2:dy // 2 + ho,
+                   dx // 2:dx // 2 + wo] for dy, dx in taps]
+    else:
+        wins = [xp[:, :, dy:dy + ho, dx:dx + wo] for dy, dx in taps]
+    # [C_out, (dy, dx, C_in)]: the windows' order
+    wt = jnp.transpose(w, (3, 0, 1, 2)).reshape(cout, 9 * cin)
+    return _dot(wt, jnp.concatenate(wins, axis=0)) + b[:, None, None, None]
 
 
 def _deconv(x, p):
-    """Stride-2 SAME 3×3 transpose conv via sub-pixel decomposition.
+    """Stride-2 SAME 3×3 transpose conv over ``x [C, N, H, W]`` via its
+    sub-pixel decomposition, returned as parity planes
+    ``[C_out, 4, N, H, W]`` (:func:`_merge` interleaves them).
 
     ``conv_transpose(k=3, s=2, SAME)`` ≡ zero-dilate + pad (2, 1) + VALID
     conv with the unflipped kernel, so output row ``2i+py`` only sees input
     rows through kernel taps ``dy ∈ {py, py+2} ∩ [0, 2]`` — each parity
-    plane is a tiny accumulation on the *small* grid, interleaved back.
+    plane is one ``_dot`` of its 1, 2 or 4 tap windows on the *small* grid.
     """
     w, b = p["w"], p["b"]
-    n, h, wd, cin = x.shape
-    cout = w.shape[-1]
-    xp = jnp.pad(x, ((0, 0), (1, 1), (1, 1), (0, 0)))
-    out = jnp.zeros((n, 2 * h, 2 * wd, cout), x.dtype)
+    cin, n, h, wd = x.shape
+    xp = jnp.pad(x, ((0, 0), (0, 0), (1, 1), (1, 1)))
+    wt = jnp.transpose(w, (0, 1, 3, 2))    # each tap a [C_out, C_in] matrix
+    planes = []
     for py in range(2):
         ytaps = [(py, py - 1)] + ([(py + 2, py)] if py + 2 <= 2 else [])
         for px in range(2):
             xtaps = [(px, px - 1)] + ([(px + 2, px)] if px + 2 <= 2 else [])
-            acc = None
-            for dy, my in ytaps:
-                for dx, mx in xtaps:
-                    win = jax.lax.slice(xp, (0, my + 1, mx + 1, 0),
-                                        (n, my + 1 + h, mx + 1 + wd, cin))
-                    t = _dot(win, w[dy, dx])
-                    acc = t if acc is None else acc + t
-            out = out.at[:, py::2, px::2, :].set(acc)
-    return out + b
+            taps = [(dy, dx, my, mx) for dy, my in ytaps for dx, mx in xtaps]
+            wins = [jax.lax.slice(xp, (0, 0, my + 1, mx + 1),
+                                  (cin, n, my + 1 + h, mx + 1 + wd))
+                    for _, _, my, mx in taps]
+            planes.append(_dot(
+                jnp.concatenate([wt[dy, dx] for dy, dx, _, _ in taps], 1),
+                jnp.concatenate(wins, axis=0)) + b[:, None, None, None])
+    return jnp.stack(planes, axis=1)
 
 
 # ---------------------------------------------------------------------------
@@ -199,24 +224,27 @@ def _deconv(x, p):
 def _conv_xla(x, p, stride=1):
     y = jax.lax.conv_general_dilated(
         x, p["w"], window_strides=(stride, stride), padding="SAME",
-        dimension_numbers=_DN)
-    return y + p["b"]
+        dimension_numbers=_DN, precision=jax.lax.Precision.HIGHEST)
+    return y + p["b"][:, None, None, None]
 
 
 def _deconv_xla(x, p):
     y = jax.lax.conv_transpose(
-        x, p["w"], strides=(2, 2), padding="SAME", dimension_numbers=_DN)
-    return y + p["b"]
+        x, p["w"], strides=(2, 2), padding="SAME", dimension_numbers=_DN,
+        precision=jax.lax.Precision.HIGHEST)
+    return _split(y + p["b"][:, None, None, None])
 
 
 def _forward_core(params, x, *, regulated, skip, conv, deconv):
     """x: [N, H, W, C_in] normalized decompressed slices -> [N, H, W, 1]
-    normalized residual prediction.  H, W are padded to multiples of 16
-    internally (replicate edges) and cropped back."""
+    normalized residual prediction.  Runs channels-first inside,
+    ``[C, N, H, W]`` (one transpose in, one out); H, W are padded to
+    multiples of 16 (replicate edges) and cropped back."""
     n, h, w, _ = x.shape
+    x = jnp.transpose(x, (3, 0, 1, 2))
     ph, pw = (-h) % 16, (-w) % 16
     if ph or pw:
-        x = jnp.pad(x, ((0, 0), (0, ph), (0, pw), (0, 0)), mode="edge")
+        x = jnp.pad(x, ((0, 0), (0, 0), (0, ph), (0, pw)), mode="edge")
 
     act = jax.nn.relu
     f0 = act(conv(x, params["conv_in"]))          # H
@@ -225,27 +253,25 @@ def _forward_core(params, x, *, regulated, skip, conv, deconv):
     f3 = act(conv(f2, params["down3"], stride=2))  # H/8
     f4 = act(conv(f3, params["down4"], stride=2))  # H/16
 
-    u = act(deconv(f4, params["up1"]))             # H/8
+    u = act(_merge(deconv(f4, params["up1"])))     # H/8
     if skip:
-        u = jnp.concatenate([u, f3], axis=-1)
-    u = act(deconv(u, params["up2"]))              # H/4
+        u = jnp.concatenate([u, f3], axis=0)
+    u = act(_merge(deconv(u, params["up2"])))      # H/4
     if skip:
-        u = jnp.concatenate([u, f2], axis=-1)
-    u = act(deconv(u, params["up3"]))              # H/2
+        u = jnp.concatenate([u, f2], axis=0)
+    u = act(_merge(deconv(u, params["up3"])))      # H/2
     if skip:
-        u = jnp.concatenate([u, f1], axis=-1)
-    u = act(deconv(u, params["up4"]))              # H
+        u = jnp.concatenate([u, f1], axis=0)
+    u = act(_merge(deconv(u, params["up4"])))      # H
     if skip:
-        u = jnp.concatenate([u, f0], axis=-1)
-    z = conv(u, params["conv_out"])                # [N,H,W,1]
+        u = jnp.concatenate([u, f0], axis=0)
+    z = conv(u, params["conv_out"])                # [1,N,H,W]
 
     if regulated:
         out = 2.0 * jax.nn.sigmoid(z) - 1.0        # (−1, 1): balanced 2×eb regulation
     else:
         out = z
-    if ph or pw:
-        out = out[:, :h, :w, :]
-    return out
+    return jnp.transpose(out[:, :, :h, :w], (1, 2, 3, 0))
 
 
 @partial(jax.jit, static_argnames=("regulated", "skip"))
@@ -257,9 +283,9 @@ def _forward_fast(params, x, *, regulated: bool = True, skip: bool = True):
 @partial(jax.jit, static_argnames=("regulated", "skip"))
 def forward_reference(params, x, *, regulated: bool = True,
                       skip: bool = True):
-    """The pre-PR9 XLA-conv forward.  Numerically ~1e-6-close to
-    :func:`forward` but NOT bit-identical; kept as the accuracy oracle and
-    the ``kernel/dnn_forward`` bench baseline."""
+    """The XLA-conv forward at ``precision=HIGHEST``.  Numerically
+    ~1e-6-close to :func:`forward` but NOT bit-identical; kept as the
+    accuracy oracle and the ``kernel/dnn_forward`` bench baseline."""
     return _forward_core(params, x, regulated=regulated, skip=skip,
                          conv=_conv_xla, deconv=_deconv_xla)
 
@@ -269,9 +295,9 @@ def forward(params, x, *, regulated: bool = True, skip: bool = True,
     """Skipping-DNN forward under the requested lowering.
 
     ``eager`` and ``jit`` are the *same* compiled bit-stable fast
-    formulation (it is jit-safe by construction — HIGHEST-precision GEMMs
-    in a fixed accumulation order leave XLA nothing to contract), so the
-    eager/jit byte-identity half of the contract holds structurally.
+    formulation (f32 multiply-and-sum taps in a fixed accumulation order),
+    so the eager/jit byte-identity half of the contract holds
+    structurally.
     There is no Pallas variant: this forward runs inside the training
     step's ``value_and_grad``, so every lowering must be differentiable,
     and ``pallas``/``auto`` resolve to this same formulation.  Traceable:

@@ -7,7 +7,8 @@ predict/quantize) — selected by ``NeurLZConfig.lowering``:
 
 * ``eager``  — the historical op-by-op path; the byte-level reference.
 * ``jit``    — jit-compiled variants with *explicit bit-stable arithmetic*:
-  contractions pinned via ``jax.lax`` ops at ``precision=HIGHEST`` and
+  contractions in exact f32 (the DNN's channels-first multiply-and-sum
+  taps) or pinned via ``jax.lax`` ops at ``precision=HIGHEST``, and
   FMA-contraction suppressed (``jax.lax.optimization_barrier`` between the
   multiply and the add at every fused-multiply-add site), so the compiled
   path produces byte-identical archives.

@@ -353,6 +353,17 @@ def test_phase_spans_nest_in_their_stage(runs, engine):
     assert parents == {n: {p} for n, p in PHASES[engine].items()}
 
 
+def test_dispatch_span_names_the_batching(runs):
+    """Each group's ``dispatch`` span says which training program ran: the
+    uniform pair stacks under ``vmap`` (the parity probe admits it), the
+    single field unrolls."""
+    tel, _, _ = runs["batched"]
+    got = [s.attrs.get("batching") for s in sorted(tel.spans,
+                                                   key=lambda s: s.t0)
+           if s.name == "dispatch"]
+    assert got == ["vmap", "unroll"]
+
+
 def _expected_transfers(engine) -> dict:
     """The transfer counters of one ``_run`` from the shapes alone.
 

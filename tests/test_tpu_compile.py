@@ -107,9 +107,9 @@ def test_fused_enhance_compiles(one_chip, no_persistent_cache, regulated,
 def test_training_epoch_compiles(one_chip, no_persistent_cache):
     """One jitted epoch of online training (what every engine dispatches)
     on 512² cross-field slices, under the lowering a TPU resolves for
-    ``auto``.  Two one-slice steps keep the compile under a minute: the
-    HIGHEST-precision tap GEMMs with 2–8 channels are what the TPU
-    compiler spends its time on, and that grows with the batch."""
+    ``auto``.  The convs are channels-first f32 multiply-and-sum taps (no
+    dot, no convolution op); two one-slice steps keep the compile near
+    20 s on a CPU host."""
     net_cfg = skipping_dnn.SkippingDNNConfig(c_in=2)
     n, batch = 2, 1
     params = jax.eval_shape(
