@@ -7,10 +7,13 @@ configured, and the controls and faults that must come out not correct.
 
 Each ``--control`` takes the ``--seeds`` that follow it; every seed is one
 op of the cell, at the cell's own size, on a snapshot of the pool its
-traffic draws (seed ``j`` of a list on snapshot ``1 + j % --snapshots``).
-One process reads them all, so the programs compile once.  Each reading
-prints one JSON line: the numbers the cell compares, whether they pass
-their limits, and the archive's bits per value.
+traffic draws (seed ``j`` of a list on snapshot ``1 + j % --snapshots`` of
+a compress cell, whose snapshot 0 is its warm-up, and ``j % --snapshots``
+of a decode cell).  In a decode cell an op is the compression of the
+snapshot, then one request for each of its fields.  One process reads them
+all, so the programs compile once.  Each reading prints one JSON line: the
+numbers the cell compares, whether they pass their limits, and the
+archive's bits per value.
 
 ``none``: the program as the configuration states it.
 
@@ -19,11 +22,12 @@ nearest precision below the configuration's float32: every answer is the
 original field rounded to bfloat16.  No program runs.
 
 ``dnn_bf16``: the program compresses as configured, then decodes the
-archive with the skipping DNN's GEMMs fed in bfloat16 (a faster decode
-whose inference no longer matches the compressor's).
+archive with the skipping DNN's channel contraction (``skipping_dnn._dot``,
+the stacked tap windows against each layer's tap weights) fed in bfloat16
+(a faster decode whose inference no longer matches the compressor's).
 
-``bf16_both``: the skipping DNN's GEMMs fed in bfloat16 at compress and at
-decode alike.
+``bf16_both``: that contraction fed in bfloat16 at compress and at decode
+alike.
 
 ``untrained``: training leaves the enhancer's state as it was (0 epochs).
 
@@ -48,18 +52,24 @@ from pathlib import Path
 ROOT = Path(__file__).resolve().parents[1]
 
 
-@contextlib.contextmanager
-def bf16_dnn():
-    """Every skipping-DNN GEMM tap on bfloat16 inputs while inside."""
+def dot_bf16(a, w):
+    """``skipping_dnn._dot``'s contraction (``a``'s last axis against
+    ``w``'s first) on bfloat16 inputs, accumulated in float32."""
     import jax
     import jax.numpy as jnp
-    from repro.core import skipping_dnn
+    return jax.lax.dot_general(
+        a.astype(jnp.bfloat16), w.astype(jnp.bfloat16),
+        (((a.ndim - 1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
 
-    def dot_bf16(a, w):
-        return jax.lax.dot_general(
-            a.astype(jnp.bfloat16), w.astype(jnp.bfloat16),
-            (((a.ndim - 1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+
+@contextlib.contextmanager
+def bf16_dnn():
+    """Every skipping-DNN channel contraction (``_dot``: each layer's tap
+    weights ``[C_out, 9 C_in]`` against its stacked windows) on bfloat16
+    inputs while inside."""
+    import jax
+    from repro.core import skipping_dnn
 
     orig = skipping_dnn._dot
     skipping_dnn._dot = dot_bf16
@@ -113,6 +123,52 @@ def pool_snapshot(cell, index: int) -> dict:
     return fields.snapshot(cfg["dataset"], common.shape(cfg), cfg["fields"],
                            tr["data_seed"], index=index,
                            coupling=cfg["coupling"], step=tr["step_rad"])
+
+
+def decode_reading(cell, control: str, seed: int, snap: dict, index: int,
+                   workdir: str) -> dict:
+    """One op of a decode cell under ``control``: the snapshot compressed
+    with the enhancer seeded by ``seed``, then one request per field, each
+    compared as the cell compares its requests.  Controls that differ only
+    at decode read the archive an earlier reading of the same seed, snapshot
+    and epochs wrote in ``workdir``."""
+    from nlzbench import harness, quality
+    from nlzbench.ops import common, decode
+    if control == "reference_bf16":
+        raise ValueError("reference_bf16 reads compress cells only")
+    cfg = dict(cell.config)
+    if control == "untrained":
+        cfg["epochs"] = 0
+    path = os.path.join(workdir, f"d{seed}_{index}_{cfg['epochs']}.nlz")
+    if not os.path.exists(path):
+        arc = common.session(cfg, seed=seed).compress(snap,
+                                                      rel_eb=cfg["rel_eb"])
+        arc.save(path)
+        del arc
+    nbytes = os.path.getsize(path)
+    recs = decode.conv_recs(path, cfg["fields"])
+    answers, replays = [], []
+    for name in cfg["fields"]:
+        if control == "conv_only":
+            got = recs[name]
+        elif control == "dnn_bf16":
+            with bf16_dnn():
+                got = decode.request(path, name)
+        else:
+            got = decode.request(path, name)
+        x = snap[name]
+        eb = quality.abs_bound(x, cfg["rel_eb"])
+        a = common.answer(1, index, name, x, got, recs[name], eb)
+        a["replay_err_over_eb"] = decode.replay_err_over_eb(path, name, got,
+                                                            recs, eb)
+        replays.append(a["replay_err_over_eb"])
+        answers.append(a)
+    checks = common.decode_checks(answers, replays, len(cfg["fields"]), cfg)
+    return {"seed": seed, "control": control, "snapshot": index,
+            "bits_per_value": 8.0 * nbytes / sum(x.size
+                                                 for x in snap.values()),
+            "answers": answers, "checks": checks,
+            "correct": all(harness.passes(c) for c in checks.values())}
 
 
 def reading(cell, control: str, seed: int, snap: dict, index: int,
@@ -187,6 +243,8 @@ def main(argv=None) -> int:
     except device.DeviceError as exc:
         print(f"control: {exc}", file=sys.stderr)
         return 3
+    decoding = cell.traffic["op"] == "decode"
+    first, read = (0, decode_reading) if decoding else (1, reading)
     pool = {}
     with tempfile.TemporaryDirectory(prefix="nlzbench-control-") as tmp:
         for control, seeds in zip(args.control, args.seeds):
@@ -197,12 +255,11 @@ def main(argv=None) -> int:
                         print(f"control: deadline reached before {control} "
                               f"seed {seed}", file=sys.stderr, flush=True)
                         return 0
-                    index = 1 + j % args.snapshots
+                    index = first + j % args.snapshots
                     if index not in pool:
                         pool[index] = pool_snapshot(cell, index)
                     t0 = time.perf_counter()
-                    out = reading(cell, control, seed, pool[index], index,
-                                  tmp)
+                    out = read(cell, control, seed, pool[index], index, tmp)
                     out["seconds"] = time.perf_counter() - t0
                     out["device"] = dev
                     print(json.dumps(out), flush=True)

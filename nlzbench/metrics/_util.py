@@ -50,3 +50,7 @@ def idle_pct(run) -> float | None:
 # The jitted programs of the batched engine's online training: the fused
 # per-group scan and the per-epoch stacked (vmap) step.
 TRAIN_PROGRAMS = ("_train_group_fused", "_epoch_vmapped")
+
+# The jitted program of a single-field decode's inference
+# (``online_trainer.predict_residual`` under ``Archive.decode``).
+INFER_PROGRAMS = ("predict_graph",)

@@ -6,9 +6,11 @@ import math
 
 from nlzbench import quality
 
-# The guarantee strict regulation states (paper section 3.3): every decoded
-# point within the bound.
-MODE_LIMIT = {"strict": 1.0}
+# The guarantees the regulation modes state (paper section 3.3): every
+# decoded point within the bound (strict, outliers stored), or within twice
+# it (relaxed: the regulated head caps the added residual at the bound).
+# Unregulated states none.
+MODE_LIMIT = {"strict": 1.0, "relaxed": 2.0}
 
 
 def shape(cfg: dict) -> tuple:
@@ -88,4 +90,24 @@ def checks(answers: list, due: int, cfg: dict) -> dict:
         "enhancer_gain_db": {"value": least, "limit": gain_limit(cfg),
                              "pass_if": ">="},
         "unanswered": {"value": max(0, due - len(per_op)), "limit": 0},
+    }
+
+
+def decode_checks(answers: list, replays: list, due: int, cfg: dict) -> dict:
+    """The numbers a decode cell compares, each with its limit: the worst
+    error of any answer in units of its bound, the least gain of any answer
+    over its conventional reconstruction alone, the worst distance of an
+    answer from the reference's replay of its archive entry, and the
+    requests that never answered."""
+    worst = max((a["max_err_over_eb"] for a in answers), default=math.inf)
+    least = min((quality.gain_db(a["conv_mse_over_eb2"], a["mse_over_eb2"])
+                 for a in answers), default=-math.inf)
+    return {
+        "max_err_over_eb": {"value": worst, "limit": error_limit(cfg)},
+        "enhancer_gain_db": {"value": least, "limit": gain_limit(cfg),
+                             "pass_if": ">="},
+        "replay_err_over_eb": {"value": max(replays, default=math.inf),
+                               "limit": float(
+                                   cfg["checks"]["replay_err_over_eb"])},
+        "unanswered": {"value": max(0, due - len(answers)), "limit": 0},
     }

@@ -15,7 +15,10 @@ for p in (str(ROOT), str(ROOT / "src")):
         sys.path.insert(0, p)
 
 SIZES = {"nyx.compress": {"slices": 32, "plane": [64, 64],
-                          "checks": {"enhancer_gain_db": 0.5}}}
+                          "checks": {"enhancer_gain_db": 0.5}},
+         "hurricane.decode": {"slices": 100, "plane": [36, 36],
+                              "checks": {"enhancer_gain_db": -0.6,
+                                         "replay_err_over_eb": 1e-4}}}
 
 
 def tiny_cell(name: str):

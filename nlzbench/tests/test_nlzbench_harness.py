@@ -24,6 +24,23 @@ def test_every_cell_resolves_and_every_metric_has_a_reader():
         assert callable(harness.metric_reader(m["name"], ROOT).read)
 
 
+def test_every_config_traffic_and_metric_file_is_named():
+    """Each file of a configuration, a traffic mix or a metric reader is the
+    one an entry of BENCHMARK.json names: none is left over unread."""
+    bench = harness.load_benchmark(ROOT)
+    nlz = ROOT / "nlzbench"
+    assert {str(p.relative_to(ROOT)) for p in nlz.glob("configs/*.json")} \
+        == {c["file"] for c in bench["configs"]}
+    assert {p.stem for p in nlz.glob("traffic/*.json")} \
+        == {w["traffic"] for w in bench["workloads"]}
+    ops = {json.loads(p.read_text())["op"] for p in nlz.glob("traffic/*.json")}
+    assert ops == {p.stem for p in nlz.glob("ops/*.py")} - {"__init__",
+                                                            "common"}
+    assert {p.stem for p in nlz.glob("metrics/*.py")
+            if not p.stem.startswith("_")} \
+        == {m["name"] for m in bench["end_to_end"] + bench["per_layer"]}
+
+
 def test_per_layer_metrics_move_a_metric_their_cells_report():
     bench = harness.load_benchmark(ROOT)
     e2e = {m["name"]: m for m in bench["end_to_end"]}
